@@ -11,7 +11,9 @@ import sys
 import time
 from typing import Callable, Dict, List
 
-OUT_DIR = os.environ.get("REPRO_BENCH_OUT", "/root/repo/bench_results")
+OUT_DIR = os.environ.get("REPRO_BENCH_OUT", os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "bench_results"))
 
 
 def ensure_out() -> str:

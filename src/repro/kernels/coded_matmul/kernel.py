@@ -25,7 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(g_ref, a_ref, x_ref, o_ref, acc_ref, *, nk: int, n: int):
+def _kernel(g_ref, a_ref, x_ref, o_ref, acc_ref, *, nk: int, k: int):
     t = pl.program_id(2)
     i = pl.program_id(3)
 
@@ -33,11 +33,12 @@ def _kernel(g_ref, a_ref, x_ref, o_ref, acc_ref, *, nk: int, n: int):
     def _init():
         acc_ref[i] = jnp.zeros_like(acc_ref[i])
 
-    g = g_ref[0, :].astype(jnp.float32)                  # (k,)
-    a = a_ref[...].astype(jnp.float32)                   # (k, bm, bk)
-    # encode in VMEM: (bm, bk) = sum_j g[j] * a[j]; the a block is fetched
-    # from HBM once per (m, n, t) and reused for all n coded outputs
-    ae = jnp.tensordot(g, a, axes=([0], [0]))
+    # encode in VMEM: (bm, bk) = sum_j G[i, j] * a[j], with the G entries
+    # read as scalars from SMEM; the a block is fetched from HBM once per
+    # (m, n, t) and reused for all n coded outputs
+    ae = g_ref[i, 0] * a_ref[0].astype(jnp.float32)
+    for j in range(1, k):
+        ae = ae + g_ref[i, j] * a_ref[j].astype(jnp.float32)
     acc_ref[i] += jax.lax.dot_general(
         ae, x_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -64,10 +65,12 @@ def coded_matmul(G: jax.Array, A: jax.Array, X: jax.Array,
     grid = (M // bm, N // bn, nk, n)
 
     return pl.pallas_call(
-        functools.partial(_kernel, nk=nk, n=n),
+        functools.partial(_kernel, nk=nk, k=k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, k), lambda m, j, t, i: (i, 0)),          # G row
+            # the whole (n, k) generator, as scalars: a (1, k) VMEM row
+            # block would break the TPU's (8, 128) tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((k, bm, bk), lambda m, j, t, i: (0, m, t)),  # A blks
             pl.BlockSpec((bk, bn), lambda m, j, t, i: (t, j)),        # X tile
         ],
@@ -75,4 +78,4 @@ def coded_matmul(G: jax.Array, A: jax.Array, X: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n, M, N), A.dtype),
         scratch_shapes=[pltpu.VMEM((n, bm, bn), jnp.float32)],
         interpret=interpret,
-    )(G, A, X)
+    )(G.astype(jnp.float32), A, X)

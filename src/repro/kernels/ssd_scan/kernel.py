@@ -30,20 +30,31 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *, q: int):
     f32 = jnp.float32
     x = x_ref[0, 0].astype(f32)          # (Q, P)
     dt = dt_ref[0, 0].astype(f32)        # (Q, 1)
-    A = a_ref[0].astype(f32)             # (1,) scalar head decay
+    A = a_ref[0, pl.program_id(1)]       # scalar head decay (SMEM)
     B = b_ref[0].astype(f32)             # (Q, N)
     C = c_ref[0].astype(f32)             # (Q, N)
 
-    dA = dt * A                          # (Q, 1)
-    lcum = jnp.cumsum(dA, axis=0)        # (Q, 1) inclusive
-    # intra-chunk attention-like term
-    diff = lcum - lcum.T                 # (Q, Q): l_t - l_s
+    # The prefix sums and the (Q, 1) -> (1, Q) flips are written as masked
+    # reductions over the (Q, Q) grid: elementwise f32 VPU work, no
+    # cumsum or transpose for the TPU compiler to refuse, and cheap next
+    # to the (Q, Q) matmuls below.
     row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    decay = jnp.where(row >= col, jnp.exp(diff), 0.0)
+    causal = row >= col
+    dA = dt * A                                                 # (Q, 1)
+    dt_r = jnp.sum(jnp.where(row == col, dt, 0.0), axis=0,
+                   keepdims=True)                               # (1, Q)
+    dA_r = dt_r * A                                             # (1, Q)
+    lcum = jnp.sum(jnp.where(causal, dA_r, 0.0), axis=1,
+                   keepdims=True)                               # (Q, 1) incl.
+    lcum_r = jnp.sum(jnp.where(row <= col, dA, 0.0), axis=0,
+                     keepdims=True)                             # (1, Q)
+    l_end = jnp.sum(dA, axis=0, keepdims=True)                  # (1, 1) = l_Q
+    # intra-chunk attention-like term
+    decay = jnp.where(causal, jnp.exp(lcum - lcum_r), 0.0)      # l_t - l_s
     cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=f32)        # (Q, Q)
-    w = cb * decay * dt.T                # (Q, Q) * dt_s broadcast on cols
+    w = cb * decay * dt_r                # (Q, Q) * dt_s broadcast on cols
     y_intra = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
                                   preferred_element_type=f32)   # (Q, P)
     # inter-chunk: y += exp(lcum) * (C @ h^T)
@@ -53,9 +64,9 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *, q: int):
     y = y_intra + ch * jnp.exp(lcum)
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # state update: h_new = h * exp(l_Q) + x^T @ (B * exp(l_Q - l) * dt)
-    tail = jnp.exp(lcum[q - 1:q] - lcum) * dt                   # (Q, 1)
+    tail = jnp.exp(l_end - lcum) * dt                           # (Q, 1)
     wb = B * tail                                               # (Q, N)
-    h_new = h * jnp.exp(lcum[q - 1, 0]) + jax.lax.dot_general(
+    h_new = h * jnp.exp(l_end) + jax.lax.dot_general(
         x, wb, (((0,), (0,)), ((), ())), preferred_element_type=f32)
     h_ref[...] = h_new
 
@@ -82,7 +93,9 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, q, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, q, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            # all H decays as SMEM scalars: a (1,) VMEM block of the
+            # rank-1 A breaks the TPU's tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, q, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, q, n), lambda bi, hi, ci: (bi, ci, 0)),
         ],
@@ -90,5 +103,5 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xk, dtk, A, Bm, Cm)
+    )(xk, dtk, A.astype(jnp.float32)[None], Bm, Cm)
     return y.transpose(0, 2, 1, 3)

@@ -19,7 +19,7 @@ import math
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
@@ -28,14 +28,27 @@ MULTI_POD = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Tiny mesh over the real local devices (smoke tests / examples)."""
+    """(data, rest) mesh over the real local devices.
+
+    Raises when ``data`` asks for more devices than exist: a mesh that
+    quietly shrank would run a different data-parallel layout than the
+    one requested."""
     n = len(jax.devices())
-    data = min(data, n)
-    return jax.make_mesh((data, max(n // data, 1))[:2], ("data", "model"))
+    if not 1 <= data <= n:
+        raise ValueError(f"make_host_mesh(data={data}) needs 1..{n} devices "
+                         f"(have {n})")
+    return _auto_mesh((data, n // data), ("data", "model"))
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the model code places activations
+    with ``with_sharding_constraint``, which only accepts Auto axes (JAX
+    0.9 makes Explicit the default)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.core.distributions import Scaling
 from repro.core.order_stats import expected_order_stat
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import TINY, parse_dist
 from repro.models import api
 
@@ -60,6 +61,7 @@ def main(argv=None):
     ap.add_argument("--straggle", default="pareto:0.05:1.8")
     ap.add_argument("--max-replicas", type=int, default=4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch).scaled(**TINY)
     dist = parse_dist(args.straggle)

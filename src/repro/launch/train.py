@@ -26,6 +26,7 @@ import numpy as np
 
 from repro import checkpoint as ckpt
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.distributions import BiModal, Pareto, Scaling, ShiftedExp
 from repro.data import DataConfig
 from repro.models import api
@@ -33,6 +34,9 @@ from repro.optim import adamw
 from repro.api import Scenario
 from repro.runtime import (CodedStepConfig, CodedTrainer, StragglerSim,
                            Telemetry, best_fr_policy)
+
+#: the key the initial weights are drawn from
+INIT_SEED = 0
 
 TINY = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
             vocab_size=512, ssm_state=16, ssm_head_dim=16, num_experts=0,
@@ -66,7 +70,28 @@ def parse_dist(spec: str):
     raise ValueError(spec)
 
 
+def model_config(arch: str, scale: str):
+    """The ``arch`` config at ``scale``: "full" as published, or cut to
+    the "tiny"/"small" widths above."""
+    cfg = get_config(arch)
+    cut = {"tiny": TINY, "small": SMALL, "full": {}}[scale]
+    return cfg.scaled(**{k: v for k, v in cut.items() if hasattr(cfg, k)})
+
+
+def planned_c(dist, n_workers: int) -> int:
+    """The planner's replication factor c* for a straggler law on
+    ``n_workers`` data-dependent workers (1 when nothing straggles)."""
+    if dist is None:
+        return 1
+    policy, _ = best_fr_policy(
+        Scenario(dist, Scaling.DATA_DEPENDENT, n_workers,
+                 delta=exo_delta(dist, 1.0)))
+    return policy.c
+
+
 def main(argv=None):
+    """Train; returns the planned ``c``, the ``trainer`` and the per-step
+    ``losses``, ``grad_norms`` and wall ``step_seconds``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--scale", choices=["tiny", "small", "full"], default="tiny")
@@ -82,26 +107,13 @@ def main(argv=None):
     ap.add_argument("--replan-every", type=int, default=25)
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_config(args.arch)
-    if args.scale == "tiny":
-        cfg = cfg.scaled(**{k: v for k, v in TINY.items()
-                            if hasattr(cfg, k)})
-    elif args.scale == "small":
-        cfg = cfg.scaled(**{k: v for k, v in SMALL.items()
-                            if hasattr(cfg, k)})
+    cfg = model_config(args.arch, args.scale)
 
     dist = parse_dist(args.straggle)
     scaling = Scaling.DATA_DEPENDENT
-    c = args.c
-    if c == 0:
-        if dist is not None:
-            policy, _ = best_fr_policy(
-                Scenario(dist, scaling, args.n_workers,
-                         delta=exo_delta(dist, 1.0)))
-            c = policy.c
-        else:
-            c = 1
+    c = args.c or planned_c(dist, args.n_workers)
     print(f"redundancy plan: n={args.n_workers} c={c} "
           f"(rate {(args.n_workers - c + 1)}/{args.n_workers})")
 
@@ -125,7 +137,7 @@ def main(argv=None):
 
     # ---- init or resume -------------------------------------------------
     start = 0
-    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    params = api.init_params(cfg, jax.random.PRNGKey(INIT_SEED))
     opt_state = adamw.init(opt_cfg, params)
     if args.ckpt_dir:
         latest = ckpt.latest_step(args.ckpt_dir)
@@ -138,14 +150,19 @@ def main(argv=None):
             print(f"resumed from step {start}")
 
     pending = None
+    losses, grad_norms, step_seconds = [], [], []
     t0 = time.time()
     for step in range(start, args.steps):
+        ts = time.perf_counter()
         params, opt_state, metrics = trainer.run_step(params, opt_state, step)
+        losses.append(float(metrics["loss"]))          # waits for the step
+        grad_norms.append(float(metrics["grad_norm"]))
+        step_seconds.append(time.perf_counter() - ts)
         if sim is not None:
             telem.record_step(sim.sample_times(step), task_size=c)
         if (step + 1) % 10 == 0:
-            print(f"step {step+1:5d} loss {float(metrics['loss']):.4f} "
-                  f"gnorm {float(metrics['grad_norm']):.3f} "
+            print(f"step {step+1:5d} loss {losses[-1]:.4f} "
+                  f"gnorm {grad_norms[-1]:.3f} "
                   f"dropped {trainer.stragglers_dropped} "
                   f"barrier-fallbacks {trainer.decode_failures}")
         if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
@@ -169,6 +186,8 @@ def main(argv=None):
     dt = time.time() - t0
     print(f"done: {args.steps - start} steps in {dt:.1f}s "
           f"({(args.steps - start)/max(dt,1e-9):.2f} steps/s)")
+    return dict(c=c, trainer=trainer, losses=losses, grad_norms=grad_norms,
+                step_seconds=step_seconds)
 
 
 if __name__ == "__main__":

@@ -70,7 +70,8 @@ def moe_ffn(
     pos_r = (pos * keep).sum(axis=2)                            # (ng, g, E)
     keep_r = keep.sum(axis=2)                                   # 0/1
     gate_r = gate.sum(axis=2)
-    oh = jax.nn.one_hot(pos_r, cap, dtype=x.dtype) * keep_r[..., None].astype(x.dtype)
+    oh = jax.nn.one_hot(pos_r.astype(jnp.int32), cap, dtype=x.dtype) \
+        * keep_r[..., None].astype(x.dtype)
     dispatch = oh                                               # (ng, g, E, C)
     combine = oh * gate_r[..., None].astype(x.dtype)            # (ng, g, E, C)
 

@@ -419,14 +419,14 @@ def _fleet_core(key, rates, speeds, cancel_overhead, dist, arrivals, delta,
               cancel_overhead, warm, dist, arrivals, delta)
     if ndev == 0:
         return run_lanes(lane_pack, shared)
-    from jax.experimental.shard_map import shard_map
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:ndev]), ("lanes",))
     P = jax.sharding.PartitionSpec
     # lanes are fully independent: lane tensors split on their lane axis
     # (axis 0 of the inputs and the final stats, axis 1 of the per-chunk
     # ys), everything else replicated
-    f = shard_map(run_lanes, mesh=mesh, in_specs=(P("lanes"), P()),
-                  out_specs=(P("lanes"), P(None, "lanes")), check_rep=False)
+    f = jax.shard_map(run_lanes, mesh=mesh, in_specs=(P("lanes"), P()),
+                      out_specs=(P("lanes"), P(None, "lanes")),
+                      check_vma=False)
     return f(lane_pack, shared)
 
 

@@ -78,6 +78,19 @@ def test_batch_spec_divisibility():
     assert batch_spec(mesh, 1) is not None        # B=1 must not crash
 
 
+def test_host_mesh_axes_are_auto():
+    """``constrain`` places activations with with_sharding_constraint,
+    which refuses Explicit mesh axes (JAX's make_mesh default)."""
+    from jax.sharding import AxisType
+    mesh = make_host_mesh()
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+
+
+def test_host_mesh_refuses_more_devices_than_exist():
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(data=len(jax.devices()) + 1)
+
+
 @pytest.mark.slow
 def test_subprocess_dryrun_single_cell():
     """One real 256-chip dry-run in a subprocess (XLA_FLAGS isolation)."""
