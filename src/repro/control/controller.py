@@ -555,6 +555,12 @@ class RedundancyController:
         fitted parameters and the re-plan scenario would then add it
         again — a double count that distorts the whole k-curve.
         """
+        with _obs_trace.span("ctl.observe"):
+            return self._observe(worker_times, timestamp, losses, latency,
+                                 completion)
+
+    def _observe(self, worker_times, timestamp, losses, latency,
+                 completion) -> Optional[ControlEvent]:
         if latency is not None and self.slo is not None:
             slo_alarm = self.slo.observe(latency)
             if slo_alarm is not None:
@@ -964,39 +970,41 @@ class RedundancyController:
                 drift: Optional[DriftEvent] = None,
                 model: Optional[FittedModel] = None,
                 quiet: bool = False) -> Optional[ControlEvent]:
-        fitted = model if model is not None else fit_window(window)
-        plan_dist, plan_delta, hedged, unit = self._hedged_plan_dist(fitted)
-        scenario = dataclasses.replace(
-            self.scenario, dist=plan_dist, delta=plan_delta)
-        if kind == "load" or (kind == "boot" and
-                              self.load_objective is not None and
-                              self.arrival_estimator.ready):
-            # a "load" commit is exactly a post-alarm (or boot/refresh)
-            # re-estimate of the arrival model; a boot in load-aware mode
-            # commits both models at once so the first plan is already
-            # load-aware.  Other commit kinds keep the COMMITTED arrival
-            # model — it is the load detector's reference, and rebasing
-            # it on every service refresh would reset the CUSUM faster
-            # than a real load change can accumulate evidence (the load
-            # channel would be blind).
-            self.arrival_model = self.arrival_estimator.model()
-            self.load_detector.rebase(self.arrival_model,
-                                      at=self._gaps_seen)
-            self._last_load_commit = self._gaps_seen
-        if kind == "failure" or (kind == "boot" and
-                                 self.loss_estimator.ready):
-            # a "failure" commit re-estimates the loss model on the
-            # post-alarm outcome stream; a boot with outcomes flowing
-            # commits it alongside so the very first plan already
-            # carries the redundancy floor.  Other commit kinds keep
-            # the COMMITTED loss model — it is the failure detector's
-            # reference (the same asymmetry as the arrival model above).
-            self.loss_model = self.loss_estimator.model()
-            self.failure_detector.rebase(self.loss_model.rate,
-                                         at=self._outcomes_seen)
-            self._last_loss_commit = self._outcomes_seen
-            self._refresh_quarantine()
-        scenario = self._degraded(scenario)
+        with _obs_trace.span("ctl.fit"):
+            fitted = model if model is not None else fit_window(window)
+            plan_dist, plan_delta, hedged, unit = \
+                self._hedged_plan_dist(fitted)
+            scenario = dataclasses.replace(
+                self.scenario, dist=plan_dist, delta=plan_delta)
+            if kind == "load" or (kind == "boot" and
+                                  self.load_objective is not None and
+                                  self.arrival_estimator.ready):
+                # a "load" commit is exactly a post-alarm (or boot/refresh)
+                # re-estimate of the arrival model; a boot in load-aware mode
+                # commits both models at once so the first plan is already
+                # load-aware.  Other commit kinds keep the COMMITTED arrival
+                # model — it is the load detector's reference, and rebasing
+                # it on every service refresh would reset the CUSUM faster
+                # than a real load change can accumulate evidence (the load
+                # channel would be blind).
+                self.arrival_model = self.arrival_estimator.model()
+                self.load_detector.rebase(self.arrival_model,
+                                          at=self._gaps_seen)
+                self._last_load_commit = self._gaps_seen
+            if kind == "failure" or (kind == "boot" and
+                                     self.loss_estimator.ready):
+                # a "failure" commit re-estimates the loss model on the
+                # post-alarm outcome stream; a boot with outcomes flowing
+                # commits it alongside so the very first plan already
+                # carries the redundancy floor.  Other commit kinds keep
+                # the COMMITTED loss model — it is the failure detector's
+                # reference (the same asymmetry as the arrival model above).
+                self.loss_model = self.loss_estimator.model()
+                self.failure_detector.rebase(self.loss_model.rate,
+                                             at=self._outcomes_seen)
+                self._last_loss_commit = self._outcomes_seen
+                self._refresh_quarantine()
+            scenario = self._degraded(scenario)
         t0 = time.perf_counter()
         self._fell_back = False
         self._tail_curve = None
@@ -1073,24 +1081,28 @@ class RedundancyController:
         # model-dependent actuation (e.g. hedged-serving replicas) must
         # track a family change even when k* happens to stay put
         rec = _obs_trace.active()
-        for a in self.actuators:
-            # actuators with an ``apply_plan`` hook additionally receive
-            # the committed plan's raw-time tail curve (None when the
-            # commit rode the closed form) — the hedged-serving delay
-            # derives from the plan, not just the model
-            plan_hook = getattr(a, "apply_plan", None)
-            if rec is None:
-                a.apply(self._policy, fitted)
-                if plan_hook is not None:
-                    plan_hook(self._policy, fitted, self._tail_curve, unit)
-            else:
-                ta = rec.now()
-                a.apply(self._policy, fitted)
-                if plan_hook is not None:
-                    plan_hook(self._policy, fitted, self._tail_curve, unit)
-                rec.event("actuate", name=type(a).__name__,
-                          dur=rec.now() - ta, at=self._seen,
-                          k=self._policy.k, switched=switched)
+        with _obs_trace.span("ctl.actuate"):
+            for a in self.actuators:
+                # actuators with an ``apply_plan`` hook additionally
+                # receive the committed plan's raw-time tail curve (None
+                # when the commit rode the closed form) — the
+                # hedged-serving delay derives from the plan, not just
+                # the model
+                plan_hook = getattr(a, "apply_plan", None)
+                if rec is None:
+                    a.apply(self._policy, fitted)
+                    if plan_hook is not None:
+                        plan_hook(self._policy, fitted, self._tail_curve,
+                                  unit)
+                else:
+                    ta = rec.now()
+                    a.apply(self._policy, fitted)
+                    if plan_hook is not None:
+                        plan_hook(self._policy, fitted, self._tail_curve,
+                                  unit)
+                    rec.event("actuate", name=type(a).__name__,
+                              dur=rec.now() - ta, at=self._seen,
+                              k=self._policy.k, switched=switched)
         self.model = fitted
         if kind not in ("load", "failure"):
             # a load/failure commit re-plans under an UNCHANGED service

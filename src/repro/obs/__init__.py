@@ -6,7 +6,8 @@ into:
 
   * :mod:`repro.obs.recorder` — a process-global structured event/span
     tracer with a bounded ring, a monotonic clock, and a JSONL
-    exporter; near-zero-overhead no-op when disabled.
+    exporter, and a switchable sink that puts its spans in a JAX
+    profiler trace; near-zero-overhead no-op when disabled.
   * :mod:`repro.obs.metrics` — named counters / gauges / streaming
     histograms (Welford + reservoir, the host twin of
     ``runtime.streamstats``) in a process-global registry.
@@ -20,13 +21,14 @@ an exported trace (:mod:`repro.obs.report`).
 from .metrics import (Counter, Gauge, MetricsRegistry,  # noqa: F401
                       REGISTRY, StreamHist)
 from .recorder import (EVENT_KINDS, Event, NULL_SPAN,  # noqa: F401
-                       Recorder, active, event, install, parse_jsonl,
-                       recording, span, uninstall)
+                       SPAN_NAMES, Recorder, active, event, install,
+                       parse_jsonl, profile_spans, recording, span,
+                       uninstall)
 from .slo import SLOAlarm, SLOMonitor  # noqa: F401
 
 __all__ = [
     "Counter", "EVENT_KINDS", "Event", "Gauge", "MetricsRegistry",
     "NULL_SPAN", "REGISTRY", "Recorder", "SLOAlarm", "SLOMonitor",
-    "StreamHist", "active", "event", "install", "parse_jsonl",
-    "recording", "span", "uninstall",
+    "SPAN_NAMES", "StreamHist", "active", "event", "install",
+    "parse_jsonl", "profile_spans", "recording", "span", "uninstall",
 ]

@@ -17,25 +17,33 @@ also carries its logical index (the CU-sample counter ``at``) in its
 fields: the decision log reconstructed from a trace is clock-free and
 bit-for-bit comparable across machines.
 
-Disabled-recorder cost: when no recorder is installed, ``active()``
-returns None, ``span()`` hands back one shared no-op singleton, and
-``event()`` returns before touching anything — instrumented hot paths
-guard with ``active()`` so the disabled path allocates no per-event
-objects (gated to <2% of ``RedundancyController.observe`` wall time by
-``tests/test_obs.py``).
+Profiler sink: ``profile_spans(True)`` makes every ``span()`` also open
+a ``jax.profiler.TraceAnnotation`` of the span's exact name for its
+extent, so the spans land in a profiler trace on the same clock as the
+device's operations.  The caller that takes the trace switches it on and
+off; it is independent of the ring (``active()`` stays None while only
+the sink is on).  Span names form the closed set ``SPAN_NAMES``.
+
+Disabled-recorder cost: with no recorder installed and the sink off,
+``active()`` returns None, ``span()`` hands back one shared no-op
+singleton, and ``event()`` returns before touching anything —
+instrumented hot paths guard with ``active()`` so the disabled path
+allocates no per-event objects (gated to <2% of
+``RedundancyController.observe`` wall time by ``tests/test_obs.py``).
 """
 from __future__ import annotations
 
 import dataclasses
 import io
 import json
+import threading
 import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Tuple, Union
 
-__all__ = ["EVENT_KINDS", "Event", "NULL_SPAN", "Recorder", "active",
-           "event", "install", "parse_jsonl", "recording", "span",
-           "uninstall"]
+__all__ = ["EVENT_KINDS", "Event", "NULL_SPAN", "Recorder", "SPAN_NAMES",
+           "active", "event", "install", "parse_jsonl", "profile_spans",
+           "recording", "span", "uninstall"]
 
 #: The event taxonomy (DESIGN.md §12).  Exporters and parsers reject
 #: unknown kinds so a trace file is schema-checked on both ends.
@@ -53,6 +61,22 @@ EVENT_KINDS = frozenset({
     "sweep",            # one cluster-engine surface call (batched/fleet rep)
     "span",             # a closed span (name, start ts, duration)
     "mark",             # free-form annotation (regime boundaries, footers)
+})
+
+#: The span names (DESIGN.md §12): one per layer boundary of the
+#: host-bound paths.  ``span()`` rejects any other name while the sink or
+#: a recorder is on, and the parser rejects it in a trace file.
+SPAN_NAMES = frozenset({
+    "ctl.observe",        # RedundancyController.observe: one sample in
+    "ctl.fit",            # _commit: fit, plan model, degradation
+    "replan",             # _commit: the planner call (ControlEvent.replan_ms)
+    "ctl.actuate",        # _commit: the actuators
+    "surface.dispatch",   # enqueueing one lane program on the device
+    "surface.fetch",      # first host read of its outputs (waits on it)
+    "surface.summarize",  # host statistics of the lanes
+    "train.batch",        # CodedTrainer.run_step: the coded batch
+    "train.decode",       # straggler mask and decode coefficients
+    "train.dispatch",     # host-to-device inputs and the step launch
 })
 
 
@@ -84,6 +108,8 @@ class Event:
         kind = obj["kind"]
         if kind not in EVENT_KINDS:
             raise ValueError(f"unknown event kind {kind!r} in trace line")
+        if kind == "span":
+            _check_span_name(obj.get("name", ""))
         fields = obj.get("fields", {})
         return Event(ts=float(obj["ts"]), kind=kind,
                      name=obj.get("name", ""),
@@ -118,27 +144,49 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """Context manager recording one ``span`` event at exit."""
+def _check_span_name(name: str) -> None:
+    if name not in SPAN_NAMES:
+        raise ValueError(f"unknown span name {name!r}; known: "
+                         f"{sorted(SPAN_NAMES)}")
 
-    __slots__ = ("_rec", "_name", "_fields", "_t0")
+
+class _Span:
+    """Context manager recording one ``span`` event at exit, with the
+    name of the span it opened inside (``parent``, None at the top), and
+    the profiler annotation of the same name while the sink is on."""
+
+    __slots__ = ("_rec", "_name", "_fields", "_t0", "_ann", "_stack")
 
     def __init__(self, rec: "Recorder", name: str, fields: dict):
         self._rec = rec
         self._name = name
         self._fields = fields
         self._t0 = 0.0
+        self._ann = None
+        self._stack = None
 
     def __enter__(self):
+        if _ANNOTATION is not None:
+            self._ann = _ANNOTATION(self._name)
+            self._ann.__enter__()
+        self._stack = stack = self._rec._open_spans()
+        parent = stack[-1] if stack else None
+        stack.append(self._name)
+        # the event's canonical fields; most spans carry none of their
+        # own, which skips the sort
+        self._fields = (("parent", parent),) if not self._fields else \
+            tuple(sorted((str(k), _canon_out(v)) for k, v in
+                         dict(self._fields, parent=parent).items()))
         self._t0 = self._rec.now()
         return self
 
     def __exit__(self, *exc):
         t1 = self._rec.now()
-        self._rec._append(Event(
-            ts=self._t0, kind="span", name=self._name, dur=t1 - self._t0,
-            fields=tuple(sorted(
-                (str(k), _canon_out(v)) for k, v in self._fields.items()))))
+        self._stack.pop()
+        self._rec._append(Event(ts=self._t0, kind="span", name=self._name,
+                                dur=t1 - self._t0, fields=self._fields))
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -177,6 +225,7 @@ class Recorder:
         self._clock = clock
         self._epoch = clock()
         self._ring: deque = deque(maxlen=int(capacity))
+        self._local = threading.local()      # each thread's open spans
         self.dropped = 0
 
     # -- clock --------------------------------------------------------------
@@ -205,8 +254,20 @@ class Recorder:
 
     def span(self, name: str, **fields) -> _Span:
         """``with rec.span("replan", k=8): ...`` records one ``span``
-        event at exit carrying the start timestamp and duration."""
+        event at exit carrying the start timestamp, the duration and the
+        enclosing span's name (field ``parent``).  ``name`` must be one
+        of ``SPAN_NAMES``."""
+        _check_span_name(name)
         return _Span(self, name, fields)
+
+    def _open_spans(self) -> list:
+        """The names of this thread's spans that are open, innermost
+        last."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
 
     # -- read side ----------------------------------------------------------
     def events(self, kind: Optional[str] = None) -> List[Event]:
@@ -248,6 +309,9 @@ def parse_jsonl(path_or_file: Union[str, io.IOBase, Iterable[str]]
 # --------------------------------------------------------------------------
 
 _ACTIVE: Optional[Recorder] = None
+
+#: ``jax.profiler.TraceAnnotation`` while the profiler sink is on, else None
+_ANNOTATION = None
 
 
 def active() -> Optional[Recorder]:
@@ -294,11 +358,35 @@ class recording:
         return False
 
 
+def profile_spans(on: bool) -> bool:
+    """Switch the profiler sink on or off; returns whether it was on.
+
+    While it is on, every ``span()`` also opens a
+    ``jax.profiler.TraceAnnotation`` named exactly as the span (fields
+    stay out of the name) for the span's extent, whether or not a
+    recorder is installed.  Whoever takes the profiler trace switches
+    it: the annotations cost a little even when no trace is taken."""
+    global _ANNOTATION
+    was = _ANNOTATION is not None
+    if on:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    else:
+        _ANNOTATION = None
+    return was
+
+
 def span(name: str, **fields):
-    """Module-level span through the global recorder; the shared no-op
-    singleton when tracing is disabled (zero allocation)."""
+    """Module-level span: through the global recorder when one is
+    installed, a bare profiler annotation when only the sink is on, and
+    the shared no-op singleton when neither is (zero allocation)."""
     rec = _ACTIVE
-    return rec.span(name, **fields) if rec is not None else NULL_SPAN
+    if rec is not None:
+        return rec.span(name, **fields)
+    if _ANNOTATION is None:
+        return NULL_SPAN
+    _check_span_name(name)
+    return _ANNOTATION(name)
 
 
 def event(kind: str, name: str = "", dur: Optional[float] = None,

@@ -749,48 +749,51 @@ def summarize_sweep(lat, busy, wasted, a_last, loads, ks, warmup, reps,
     (a cell where every job failed reports inf), and ``failure_rate``
     is the failed fraction per cell.
     """
-    lat = np.asarray(lat, np.float64)            # (reps, L, K, num_jobs)
-    busy = np.asarray(busy, np.float64)          # (reps, L, K)
-    wasted = np.asarray(wasted, np.float64)
-    a_last = np.asarray(a_last, np.float64)      # (reps, L)
-    if horizon is None:
-        horizon = a_last[:, :, None] + lat[..., -1]  # D_last (monotone in j)
-    else:
-        horizon = np.asarray(horizon, np.float64)
-    steady = lat[..., warmup:]
-    L, K = len(loads), len(ks)
-    pooled = np.moveaxis(steady, 0, -2).reshape(L, K, -1)
-    if ok is None:
-        mean = pooled.mean(axis=-1)
-        p50 = np.quantile(pooled, 0.50, axis=-1)
-        p95 = np.quantile(pooled, 0.95, axis=-1)
-        p99 = np.quantile(pooled, 0.99, axis=-1)
-        fail_rate = None
-        completions = float(num_jobs)
-    else:
-        ok = np.asarray(ok, bool)
-        ok_pooled = np.moveaxis(ok[..., warmup:], 0, -2).reshape(L, K, -1)
-        mean = np.full((L, K), np.inf)
-        p50, p95, p99 = (np.full((L, K), np.inf) for _ in range(3))
-        for i in range(L):
-            for j in range(K):
-                good = pooled[i, j][ok_pooled[i, j]]
-                if good.size:
-                    mean[i, j] = good.mean()
-                    p50[i, j] = np.quantile(good, 0.50)
-                    p95[i, j] = np.quantile(good, 0.95)
-                    p99[i, j] = np.quantile(good, 0.99)
-        fail_rate = 1.0 - ok_pooled.mean(axis=-1)
-        completions = np.asarray(ok, bool).sum(axis=-1)  # (reps, L, K)
-    return ClusterSweep(
-        loads=tuple(loads), ks=tuple(ks), warmup=int(warmup),
-        reps=int(reps),
-        mean=mean, p50=p50, p95=p95, p99=p99,
-        utilization=(busy / (n * horizon)).mean(axis=0),
-        wasted_frac=(wasted / np.maximum(busy, 1e-12)).mean(axis=0),
-        throughput=(completions / horizon).mean(axis=0),
-        failure_rate=fail_rate,
-    )
+    with _trace.span("surface.summarize"):
+        with _trace.span("surface.fetch"):
+            lat = np.asarray(lat, np.float64)        # (reps, L, K, num_jobs)
+            busy = np.asarray(busy, np.float64)      # (reps, L, K)
+            wasted = np.asarray(wasted, np.float64)
+            a_last = np.asarray(a_last, np.float64)  # (reps, L)
+        if horizon is None:
+            # D_last (monotone in j)
+            horizon = a_last[:, :, None] + lat[..., -1]
+        else:
+            horizon = np.asarray(horizon, np.float64)
+        steady = lat[..., warmup:]
+        L, K = len(loads), len(ks)
+        pooled = np.moveaxis(steady, 0, -2).reshape(L, K, -1)
+        if ok is None:
+            mean = pooled.mean(axis=-1)
+            p50 = np.quantile(pooled, 0.50, axis=-1)
+            p95 = np.quantile(pooled, 0.95, axis=-1)
+            p99 = np.quantile(pooled, 0.99, axis=-1)
+            fail_rate = None
+            completions = float(num_jobs)
+        else:
+            ok = np.asarray(ok, bool)
+            ok_pooled = np.moveaxis(ok[..., warmup:], 0, -2).reshape(L, K, -1)
+            mean = np.full((L, K), np.inf)
+            p50, p95, p99 = (np.full((L, K), np.inf) for _ in range(3))
+            for i in range(L):
+                for j in range(K):
+                    good = pooled[i, j][ok_pooled[i, j]]
+                    if good.size:
+                        mean[i, j] = good.mean()
+                        p50[i, j] = np.quantile(good, 0.50)
+                        p95[i, j] = np.quantile(good, 0.95)
+                        p99[i, j] = np.quantile(good, 0.99)
+            fail_rate = 1.0 - ok_pooled.mean(axis=-1)
+            completions = np.asarray(ok, bool).sum(axis=-1)  # (reps, L, K)
+        return ClusterSweep(
+            loads=tuple(loads), ks=tuple(ks), warmup=int(warmup),
+            reps=int(reps),
+            mean=mean, p50=p50, p95=p95, p99=p99,
+            utilization=(busy / (n * horizon)).mean(axis=0),
+            wasted_frac=(wasted / np.maximum(busy, 1e-12)).mean(axis=0),
+            throughput=(completions / horizon).mean(axis=0),
+            failure_rate=fail_rate,
+        )
 
 
 def sweep(scenario: Scenario, loads: Sequence[float],
@@ -848,12 +851,14 @@ def sweep(scenario: Scenario, loads: Sequence[float],
     rec = _trace.active()
     traces0 = _SWEEP_TRACES
     t0 = rec.now() if rec is not None else 0.0
-    out = _sweep_kernel(
-        jax.random.PRNGKey(seed), jnp.asarray(loads, jnp.float32), speeds,
-        jnp.float32(cancel_overhead), scenario.dist, scenario.scaling, n,
-        ks, int(num_jobs), int(reps), bool(preempt), arrivals,
-        None if scenario.delta is None else float(scenario.delta),
-        failures, retry, groups, group_r, group_ids)
+    with _trace.span("surface.dispatch"):
+        out = _sweep_kernel(
+            jax.random.PRNGKey(seed), jnp.asarray(loads, jnp.float32),
+            speeds, jnp.float32(cancel_overhead), scenario.dist,
+            scenario.scaling, n, ks, int(num_jobs), int(reps),
+            bool(preempt), arrivals,
+            None if scenario.delta is None else float(scenario.delta),
+            failures, retry, groups, group_r, group_ids)
     if rec is not None:
         rec.event("sweep", name="batched", dur=rec.now() - t0,
                   n=n, num_jobs=int(num_jobs), reps=int(reps),

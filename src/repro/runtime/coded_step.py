@@ -32,6 +32,7 @@ from ..data.pipeline import (DataConfig, coded_batch, decode_example_weights,
                              expand_worker_weights)
 from ..models import api
 from ..models.layers import cross_entropy_loss
+from ..obs import recorder as _trace
 from ..optim import adamw
 
 
@@ -251,8 +252,11 @@ class CodedTrainer:
         return alive
 
     def run_step(self, params, opt_state, step: int):
-        toks, labs = coded_batch(self.data_cfg, step, self.step_cfg.code)
-        alive = self.gather_alive(step)
-        a = self.decode_coefficients(alive)
-        return self.step_fn(params, opt_state, jnp.asarray(toks),
-                            jnp.asarray(labs), jnp.asarray(a))
+        with _trace.span("train.batch"):
+            toks, labs = coded_batch(self.data_cfg, step, self.step_cfg.code)
+        with _trace.span("train.decode"):
+            alive = self.gather_alive(step)
+            a = self.decode_coefficients(alive)
+        with _trace.span("train.dispatch"):
+            return self.step_fn(params, opt_state, jnp.asarray(toks),
+                                jnp.asarray(labs), jnp.asarray(a))

@@ -95,19 +95,6 @@ def fleet_compile_count() -> int:
     return _FLEET_TRACES
 
 
-def _peak_rss_mb() -> float:
-    """Peak resident set of this process in MB (ru_maxrss is KB on
-    Linux, bytes on macOS); -1.0 where ``resource`` is unavailable."""
-    try:
-        import resource
-        import sys
-        peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-        return peak / (1024.0 * 1024.0) if sys.platform == "darwin" \
-            else peak / 1024.0
-    except Exception:
-        return -1.0
-
-
 def default_chunk(num_jobs: int) -> int:
     """The ``chunk_size=None`` resolution: one chunk for small traces; at
     scale, the smallest chunk that keeps the chunk COUNT of the 512
@@ -517,59 +504,67 @@ def run_fleet(scenario: Scenario, loads: Sequence[float], lanes: FleetLanes,
             jax.random.split(jax.random.PRNGKey(seed), int(reps))):
         traces0 = _FLEET_TRACES
         t0 = rec.now() if rec is not None else 0.0
-        statsf, ys = _fleet_kernel(
-            rk, jnp.asarray(rates), speeds, jnp.float32(cancel_overhead),
-            scenario.dist, arrivals, delta,
-            failures if have_fail else None, jnp.int32(warmup),
-            jnp.asarray(lk), jnp.asarray(ls), jnp.asarray(lr),
-            jnp.asarray(gid), scaling=scenario.scaling, n=n,
-            num_jobs=int(num_jobs), chunk=int(chunk), preempt=bool(preempt),
-            retry=retry, grouped=lanes.grouped, groups=lanes.groups,
-            stream=bool(stream), reservoir=int(reservoir), ndev=ndev,
-            s_max=s_max)
-        ysn = {k: np.asarray(v)[:, :B] for k, v in ys.items()}  # (C, B, ...)
-        al_c = ysn["a_last"].astype(np.float64)
-        a_abs = np.cumsum(al_c, axis=0)
-        a_fin = a_abs[-1]                                       # (B,)
-        acc["busy"].append(
-            ysn["busy"].astype(np.float64).sum(0).reshape(L, KL))
-        acc["wasted"].append(
-            ysn["wasted"].astype(np.float64).sum(0).reshape(L, KL))
-        acc["a_last"].append(a_fin.reshape(L, KL)[:, 0])
-        if have_fail:
-            base_before = a_abs - al_c
-            horizon = (base_before + ysn["hrel"].astype(np.float64)).max(0)
-            acc["nok"].append(
-                ysn["nok"].astype(np.float64).sum(0).reshape(L, KL))
-        else:
-            horizon = a_fin + ysn["last"][-1].astype(np.float64)
-        acc["horizon"].append(horizon.reshape(L, KL))
-        if stream:
-            cnt, mean, m2, res = (np.asarray(x)[:B] for x in statsf)
-            acc["cnt"].append(cnt.reshape(L, KL))
-            acc["mean"].append(mean.reshape(L, KL))
-            acc["m2"].append(m2.reshape(L, KL))
-            acc["res"].append(res.reshape(L, KL, -1))
-        else:
-            lat = np.moveaxis(ysn["lat"], 0, 1).reshape(B, -1)[:, :num_jobs]
-            okc = np.moveaxis(ysn["ok"], 0, 1).reshape(B, -1)[:, :num_jobs]
-            acc["lat"].append(
-                lat.astype(np.float64).reshape(L, KL, num_jobs))
+        with _trace.span("surface.dispatch"):
+            statsf, ys = _fleet_kernel(
+                rk, jnp.asarray(rates), speeds,
+                jnp.float32(cancel_overhead), scenario.dist, arrivals, delta,
+                failures if have_fail else None, jnp.int32(warmup),
+                jnp.asarray(lk), jnp.asarray(ls), jnp.asarray(lr),
+                jnp.asarray(gid), scaling=scenario.scaling, n=n,
+                num_jobs=int(num_jobs), chunk=int(chunk),
+                preempt=bool(preempt), retry=retry, grouped=lanes.grouped,
+                groups=lanes.groups,
+                stream=bool(stream), reservoir=int(reservoir), ndev=ndev,
+                s_max=s_max)
+        with _trace.span("surface.summarize"):
+            with _trace.span("surface.fetch"):               # (C, B, ...)
+                ysn = {k: np.asarray(v)[:, :B] for k, v in ys.items()}
+            al_c = ysn["a_last"].astype(np.float64)
+            a_abs = np.cumsum(al_c, axis=0)
+            a_fin = a_abs[-1]                                   # (B,)
+            acc["busy"].append(
+                ysn["busy"].astype(np.float64).sum(0).reshape(L, KL))
+            acc["wasted"].append(
+                ysn["wasted"].astype(np.float64).sum(0).reshape(L, KL))
+            acc["a_last"].append(a_fin.reshape(L, KL)[:, 0])
             if have_fail:
-                acc["ok"].append(okc.astype(bool).reshape(L, KL, num_jobs))
+                base_before = a_abs - al_c
+                horizon = (base_before
+                           + ysn["hrel"].astype(np.float64)).max(0)
+                acc["nok"].append(
+                    ysn["nok"].astype(np.float64).sum(0).reshape(L, KL))
+            else:
+                horizon = a_fin + ysn["last"][-1].astype(np.float64)
+            acc["horizon"].append(horizon.reshape(L, KL))
+            if stream:
+                with _trace.span("surface.fetch"):
+                    cnt, mean, m2, res = (np.asarray(x)[:B]
+                                          for x in statsf)
+                acc["cnt"].append(cnt.reshape(L, KL))
+                acc["mean"].append(mean.reshape(L, KL))
+                acc["m2"].append(m2.reshape(L, KL))
+                acc["res"].append(res.reshape(L, KL, -1))
+            else:
+                lat = np.moveaxis(ysn["lat"], 0, 1).reshape(
+                    B, -1)[:, :num_jobs]
+                okc = np.moveaxis(ysn["ok"], 0, 1).reshape(
+                    B, -1)[:, :num_jobs]
+                acc["lat"].append(
+                    lat.astype(np.float64).reshape(L, KL, num_jobs))
+                if have_fail:
+                    acc["ok"].append(
+                        okc.astype(bool).reshape(L, KL, num_jobs))
         if rec is not None:
             # per-REPLICATION granularity: the chunk loop is a lax.scan
             # inside the jit boundary, so the host (and the recorder)
             # cannot see individual chunks — DESIGN.md §12 documents
-            # the boundary.  Progress + peak RSS per warm-executable
-            # call is the bounded-memory story this engine exists for.
+            # the boundary.
             rec.event("sweep", name="fleet", dur=rec.now() - t0,
                       rep=rep, reps=int(reps), n=n, lanes=B,
                       num_chunks=-(-int(num_jobs) // int(chunk)),
                       chunk=int(chunk), jobs=int(num_jobs),
                       stream=bool(stream),
-                      compiled=_FLEET_TRACES > traces0,
-                      rss_mb=_peak_rss_mb())
+                      compiled=_FLEET_TRACES > traces0)
 
     def stk(name):
         return np.stack(acc[name]) if acc[name] else None
@@ -607,33 +602,34 @@ def summarize_fleet(raw: FleetRaw, ks: Sequence[int],
             raw.warmup, raw.reps, raw.num_jobs, raw.n,
             ok=None if raw.ok is None else raw.ok[:, :, sl],
             horizon=horizon)
-    cnt = raw.cnt[:, :, sl].reshape(raw.reps, -1)
-    tot, mean, _ = welford_finalize_host(
-        cnt, raw.mean[:, :, sl].reshape(raw.reps, -1),
-        raw.m2[:, :, sl].reshape(raw.reps, -1))
-    R = raw.res.shape[-1]
-    vals = reservoir_values_host(
-        raw.res[:, :, sl].reshape(raw.reps, -1, R), cnt)
-    qs = np.full((3, L * K), np.inf)
-    for i, v in enumerate(vals):
-        if v.size:
-            qs[:, i] = np.quantile(v, [0.50, 0.95, 0.99])
-    mean = np.where(tot > 0, mean, np.inf).reshape(L, K)
-    if raw.have_fail:
-        completions = raw.nok[:, :, sl]
-        fail = (1.0 - cnt.sum(axis=0)
-                / (raw.reps * (raw.num_jobs - raw.warmup))).reshape(L, K)
-    else:
-        completions = float(raw.num_jobs)
-        fail = None
-    return ClusterSweep(
-        loads=loads, ks=ks, warmup=raw.warmup, reps=raw.reps, mean=mean,
-        p50=qs[0].reshape(L, K), p95=qs[1].reshape(L, K),
-        p99=qs[2].reshape(L, K),
-        utilization=(busy / (raw.n * horizon)).mean(axis=0),
-        wasted_frac=(wasted / np.maximum(busy, 1e-12)).mean(axis=0),
-        throughput=(completions / horizon).mean(axis=0),
-        failure_rate=fail)
+    with _trace.span("surface.summarize"):
+        cnt = raw.cnt[:, :, sl].reshape(raw.reps, -1)
+        tot, mean, _ = welford_finalize_host(
+            cnt, raw.mean[:, :, sl].reshape(raw.reps, -1),
+            raw.m2[:, :, sl].reshape(raw.reps, -1))
+        R = raw.res.shape[-1]
+        vals = reservoir_values_host(
+            raw.res[:, :, sl].reshape(raw.reps, -1, R), cnt)
+        qs = np.full((3, L * K), np.inf)
+        for i, v in enumerate(vals):
+            if v.size:
+                qs[:, i] = np.quantile(v, [0.50, 0.95, 0.99])
+        mean = np.where(tot > 0, mean, np.inf).reshape(L, K)
+        if raw.have_fail:
+            completions = raw.nok[:, :, sl]
+            fail = (1.0 - cnt.sum(axis=0)
+                    / (raw.reps * (raw.num_jobs - raw.warmup))).reshape(L, K)
+        else:
+            completions = float(raw.num_jobs)
+            fail = None
+        return ClusterSweep(
+            loads=loads, ks=ks, warmup=raw.warmup, reps=raw.reps, mean=mean,
+            p50=qs[0].reshape(L, K), p95=qs[1].reshape(L, K),
+            p99=qs[2].reshape(L, K),
+            utilization=(busy / (raw.n * horizon)).mean(axis=0),
+            wasted_frac=(wasted / np.maximum(busy, 1e-12)).mean(axis=0),
+            throughput=(completions / horizon).mean(axis=0),
+            failure_rate=fail)
 
 
 def trim_raw_loads(raw: FleetRaw, num_loads: int) -> FleetRaw:

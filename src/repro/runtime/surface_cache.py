@@ -63,7 +63,6 @@ _LOAD_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)
 #: it mirrors jit executable state, not a statistic.
 _C_HITS = _metrics.REGISTRY.counter("surface_cache.hits")
 _C_MISSES = _metrics.REGISTRY.counter("surface_cache.misses")
-_H_COMPILE_MS = _metrics.REGISTRY.hist("surface_cache.compile_ms")
 _KEYS: Dict[tuple, int] = {}
 
 
@@ -115,8 +114,7 @@ def record_cache_key(cache_key: tuple) -> bool:
     rec = _trace.active()
     if rec is not None:
         rec.event("cache_hit" if warm else "cache_miss",
-                  name="surface_cache", family=str(cache_key[0]),
-                  key=str(cache_key))
+                  name="surface_cache", family=str(cache_key[0]))
     return warm
 
 
@@ -155,11 +153,10 @@ def _cached_fleet(scenario, loads, ks, num_jobs, reps, preempt,
 
 
 def _record_surface_call(warm: bool, wall_ms: float, which: str) -> None:
-    """Metrics + trace for one surface call: a MISS's wall time includes
-    the XLA trace and lands on the compile histogram and a ``compile``
-    event; a HIT is a kernel launch and stays metrics-only."""
+    """Trace one surface call: a MISS's wall time includes the XLA trace
+    and lands on a ``compile`` event; a HIT is a kernel launch and is not
+    recorded."""
     if not warm:
-        _H_COMPILE_MS.update(wall_ms)
         rec = _trace.active()
         if rec is not None:
             rec.event("compile", name=which, wall_ms=wall_ms)
@@ -238,26 +235,28 @@ def cached_sweep(scenario: Scenario, loads: Sequence[float],
          retry, None if lanes is None else lanes.signature))
 
     t0 = time.perf_counter()
-    out = _cached_kernel(
-        jax.random.PRNGKey(seed), jnp.asarray(padded, jnp.float32), speeds,
-        jnp.float32(cancel_overhead), scenario.dist, scenario.scaling, n,
-        ks, int(num_jobs), int(reps), bool(preempt), arrivals,
-        None if scenario.delta is None else jnp.float32(scenario.delta),
-        failures, retry, groups, group_r, group_ids)
+    with _trace.span("surface.dispatch"):
+        out = _cached_kernel(
+            jax.random.PRNGKey(seed), jnp.asarray(padded, jnp.float32),
+            speeds, jnp.float32(cancel_overhead), scenario.dist,
+            scenario.scaling, n, ks, int(num_jobs), int(reps),
+            bool(preempt), arrivals,
+            None if scenario.delta is None else jnp.float32(scenario.delta),
+            failures, retry, groups, group_r, group_ids)
     _record_surface_call(warm, (time.perf_counter() - t0) * 1e3,
                          "cached_sweep")
 
     # trim the padded lanes before aggregation: the surviving cells are
     # lane-independent under vmap, so they match the unpadded kernel
-    if retry is None:
-        lat, busy, wasted, a_last = out
-        ok = horizon = None
-    else:
-        lat, busy, wasted, a_last, ok, horizon = out
-        ok = np.asarray(ok)[:, :L]
-        horizon = np.asarray(horizon)[:, :L]
-    return summarize_sweep(np.asarray(lat)[:, :L], np.asarray(busy)[:, :L],
-                           np.asarray(wasted)[:, :L],
-                           np.asarray(a_last)[:, :L],
-                           loads, ks, warmup, reps, num_jobs, n,
-                           ok=ok, horizon=horizon)
+    with _trace.span("surface.fetch"):
+        if retry is None:
+            lat, busy, wasted, a_last = out
+            ok = horizon = None
+        else:
+            lat, busy, wasted, a_last, ok, horizon = out
+            ok = np.asarray(ok)[:, :L]
+            horizon = np.asarray(horizon)[:, :L]
+        lat, busy = np.asarray(lat)[:, :L], np.asarray(busy)[:, :L]
+        wasted, a_last = np.asarray(wasted)[:, :L], np.asarray(a_last)[:, :L]
+    return summarize_sweep(lat, busy, wasted, a_last, loads, ks, warmup,
+                           reps, num_jobs, n, ok=ok, horizon=horizon)

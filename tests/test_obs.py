@@ -1,7 +1,9 @@
 """Observability plane (DESIGN.md §12): the flight recorder, the
 metrics registry, the streaming SLO monitor, run reports, and the
-instrumented hot paths — including the disabled-recorder overhead gate
-and the trace-vs-decision-log bit-for-bit contract."""
+instrumented hot paths — including the disabled-recorder overhead gate,
+the trace-vs-decision-log bit-for-bit contract, and the spans the
+profiler sink puts in a JAX profiler trace."""
+import glob
 import io
 import time
 import tracemalloc
@@ -15,9 +17,9 @@ from repro.control import controller as controller_mod
 from repro.core import (BiModal, Pareto, Regime, Scaling, ShiftedExp,
                         sample_regime_trace)
 from repro.core.scenario import PoissonArrivals
-from repro.obs import (EVENT_KINDS, NULL_SPAN, REGISTRY, Event,
+from repro.obs import (EVENT_KINDS, NULL_SPAN, REGISTRY, SPAN_NAMES, Event,
                        MetricsRegistry, Recorder, SLOMonitor, StreamHist,
-                       active, parse_jsonl, recording)
+                       active, parse_jsonl, profile_spans, recording)
 from repro.obs import recorder as recorder_mod
 from repro.obs.report import (decision_log, decision_log_from_control_events,
                               render_report)
@@ -104,6 +106,56 @@ class TestRecorder:
         assert active() is None
         recorder_mod.event("mark", name="ignored")   # must not raise
 
+    def test_ring_spans_carry_their_parents_name(self):
+        with recording() as rec:
+            with recorder_mod.span("ctl.observe"):
+                with recorder_mod.span("replan", kind="load"):
+                    with recorder_mod.span("surface.fetch"):
+                        pass
+                with recorder_mod.span("ctl.actuate"):
+                    pass
+            with recorder_mod.span("train.batch"):
+                pass
+        got = [(e.name, e.field_dict()["parent"]) for e in rec.events()]
+        assert got == [("surface.fetch", "replan"),
+                       ("replan", "ctl.observe"),
+                       ("ctl.actuate", "ctl.observe"),
+                       ("ctl.observe", None), ("train.batch", None)]
+        assert rec.events()[1].field_dict()["kind"] == "load"
+        assert parse_jsonl(io.StringIO(
+            "\n".join(e.to_json() for e in rec.events()))) == rec.events()
+
+    def test_unknown_span_name_rejected_while_anything_listens(self):
+        with recording() as rec:
+            with pytest.raises(ValueError, match="unknown span name"):
+                recorder_mod.span("nope")
+            with pytest.raises(ValueError, match="unknown span name"):
+                rec.span("replan ")
+        profile_spans(True)
+        try:
+            with pytest.raises(ValueError, match="unknown span name"):
+                recorder_mod.span("surface")
+        finally:
+            profile_spans(False)
+        with pytest.raises(ValueError, match="unknown span name"):
+            Event.from_json('{"ts": 0.0, "kind": "span", "name": "nope", '
+                            '"dur": 1.0, "fields": {}}')
+        assert recorder_mod.span("nope") is NULL_SPAN     # nothing listens
+
+    def test_profiler_sink_alone_leaves_the_recorder_off(self):
+        assert profile_spans(True) is False
+        try:
+            assert active() is None
+            sp = recorder_mod.span("replan", kind="load")
+            assert sp is not NULL_SPAN
+            with sp:
+                recorder_mod.event("mark", name="ignored")
+        finally:
+            assert profile_spans(False) is True
+        assert recorder_mod.span("replan") is NULL_SPAN
+        assert set(SPAN_NAMES) >= {"ctl.observe", "replan",
+                                   "surface.fetch", "train.dispatch"}
+
     def test_numpy_fields_canonicalize_to_python_scalars(self):
         rec = Recorder()
         rec.event("mark", a=np.int64(3), b=np.float64(0.5), c=[1, 2])
@@ -119,8 +171,10 @@ class TestRecorder:
 class TestDisabledOverhead:
     def test_observe_loop_overhead_under_two_percent(self):
         """The disabled path costs one ``active()`` read per
-        instrumented site.  Bound: sites-per-observe * per-guard cost
-        must be under 2% of one ``observe()`` call's wall time."""
+        instrumented site, and a ``with`` on the shared no-op span per
+        span site.  Bound: the sites one observe can hit, each at its
+        own cost, must be under 2% of one ``observe()`` call's wall
+        time."""
         assert active() is None
         ctl = RedundancyController(PRIOR)
         x = np.full(N, 11.0)
@@ -136,10 +190,19 @@ class TestDisabledOverhead:
         for _ in range(guards):
             active()
         guard_s = (time.perf_counter() - t0) / guards
-        # generous ceiling on instrumented sites one observe can hit
-        sites_per_observe = 16
-        assert sites_per_observe * guard_s < 0.02 * observe_s, (
-            f"guard {guard_s * 1e9:.1f} ns x {sites_per_observe} sites vs "
+        t0 = time.perf_counter()
+        for _ in range(guards):
+            with recorder_mod.span("ctl.observe"):
+                pass
+        span_s = (time.perf_counter() - t0) / guards
+        # generous ceiling on instrumented sites one observe can hit;
+        # span sites: ctl.observe, and ctl.fit, replan, ctl.actuate on
+        # a commit
+        sites_per_observe, spans_per_observe = 16, 4
+        cost = sites_per_observe * guard_s + spans_per_observe * span_s
+        assert cost < 0.02 * observe_s, (
+            f"guard {guard_s * 1e9:.1f} ns x {sites_per_observe} sites + "
+            f"span {span_s * 1e9:.1f} ns x {spans_per_observe} vs "
             f"observe {observe_s * 1e6:.1f} us")
 
     def test_disabled_path_allocates_no_event_objects(self):
@@ -493,7 +556,158 @@ class TestEngineSweepEvents:
         assert [e.name for e in evs] == ["fleet", "fleet"]
         f = evs[0].field_dict()
         assert f["rep"] == 0 and f["num_chunks"] == 3
-        assert f["rss_mb"] > 0 or f["rss_mb"] == -1.0
+
+
+# ==========================================================================
+# The profiler sink: program spans in a JAX profiler trace
+# ==========================================================================
+
+SPAN_SC = Scenario(ShiftedExp(1.0, 10.0), SERVER, N)
+SPAN_SWEEP = dict(loads=[0.01], ks=[1, 2], num_jobs=40, reps=1, seed=0,
+                  preempt=False)
+
+
+def _span_controller():
+    """A load-aware controller on the cached chunked engine (two reps,
+    so two launches per re-plan) that commits within a dozen samples."""
+    from repro.control.controller import ControllerConfig
+    return RedundancyController(
+        SPAN_SC, objective=LoadAwareLatency(
+            num_jobs=60, reps=2, backend="cached", chunk_size=32,
+            preempt=False),
+        config=ControllerConfig(boot_samples=24, arrival_min_gaps=4,
+                                arrival_refit_gaps=4))
+
+
+def _span_loop(ctl, steps=12):
+    rng = np.random.default_rng(0)
+    t, events = 0.0, []
+    for _ in range(steps):
+        t += rng.exponential(50.0)
+        events.append(ctl.observe(1.0 + rng.exponential(10.0, N),
+                                  timestamp=t))
+    return [e for e in events if e is not None]
+
+
+def _span_trainer():
+    from repro.configs.base import ModelConfig
+    from repro.data import DataConfig
+    from repro.models import api
+    from repro.optim import adamw
+    from repro.runtime import CodedStepConfig, CodedTrainer
+    cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=32,
+                      num_heads=2, num_kv_heads=1, d_ff=64, vocab_size=64,
+                      flash_block_kv=8, remat="none",
+                      compute_dtype="float32", param_dtype="float32")
+    import jax
+    trainer = CodedTrainer(cfg, DataConfig(vocab_size=64, seq_len=8,
+                                           global_batch=4),
+                           CodedStepConfig(n_workers=4, c=2, unique_batch=4),
+                           adamw.AdamWConfig(lr=1e-3))
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    return trainer, params, adamw.init(trainer.opt_cfg, params)
+
+
+def _span_workload(trainer, params, opt):
+    """The controller's commit path, a monolithic cached surface, a
+    chunked fleet surface and a coded train step."""
+    from repro.runtime.fleet import fleet_sweep
+    from repro.runtime.surface_cache import cached_sweep
+    commits = _span_loop(_span_controller())
+    cached_sweep(SPAN_SC, **SPAN_SWEEP)
+    fleet_sweep(SPAN_SC, chunk_size=20, **SPAN_SWEEP)
+    params, opt, m = trainer.run_step(params, opt, 0)   # donates its input
+    float(m["loss"])
+    return commits, params, opt
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """The span workload, warmed, then run once under a profiler trace
+    with the sink on: its commits and the program spans of the host
+    planes as (name, start ns, end ns), by host thread."""
+    import jax
+    from jax.profiler import ProfileData
+    trainer, params, opt = _span_trainer()
+    _, params, opt = _span_workload(trainer, params, opt)   # compile outside
+    d = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    was = profile_spans(True)
+    try:
+        with jax.profiler.trace(d, profiler_options=opts):
+            assert active() is None
+            commits, _, _ = _span_workload(trainer, params, opt)
+    finally:
+        profile_spans(was)
+    path, = glob.glob(f"{d}/**/*.xplane.pb", recursive=True)
+    threads = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                     for ev in line.events if ev.name in SPAN_NAMES]
+            if spans:
+                threads.append(spans)
+    return commits, threads
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+class TestProfilerSpans:
+    def test_every_span_reaches_the_host_plane_by_its_exact_name(
+            self, profiled):
+        commits, threads = profiled
+        assert commits, "the traced loop must commit a plan"
+        assert len(threads) == 1               # one host thread runs it all
+        assert {name for name, _, _ in threads[0]} == set(SPAN_NAMES)
+
+    def test_fetch_nests_in_replan_nests_in_observe(self, profiled):
+        commits, (spans,) = profiled
+        replans = [s for s in spans if s[0] == "replan"]
+        assert len(replans) == len(commits)
+        for r in replans:
+            o, = [s for s in spans if s[0] == "ctl.observe" and _inside(r, s)]
+            inner = [s[0] for s in spans if _inside(s, r) and s != r]
+            # one launch per replication, each fetched and summarized
+            assert inner.count("surface.dispatch") == 2
+            assert "surface.fetch" in inner and "surface.summarize" in inner
+            beside = {s[0] for s in spans
+                      if _inside(s, o) and not _inside(s, r)}
+            assert beside == {"ctl.observe", "ctl.fit", "ctl.actuate"}
+
+    def test_surfaces_and_train_step_outside_the_controller(self, profiled):
+        _, (spans,) = profiled
+        obs = [s for s in spans if s[0] == "ctl.observe"]
+        alone = [s for s in spans
+                 if not any(_inside(s, o) for o in obs)]
+        names = [s[0] for s in alone]
+        # cached_sweep: 1 launch; fleet_sweep: 1 launch (one rep)
+        assert names.count("surface.dispatch") == 2
+        assert "surface.fetch" in names and "surface.summarize" in names
+        train = [s for s in alone if s[0].startswith("train.")]
+        assert [s[0] for s in sorted(train, key=lambda s: s[1])] == \
+            ["train.batch", "train.decode", "train.dispatch"]
+
+    def test_ring_spans_on_the_commit_path_name_their_parents(self):
+        ctl = _span_controller()
+        with recording() as rec:
+            commits = _span_loop(ctl)
+        assert commits
+        parents = {}
+        for e in rec.events("span"):
+            parents.setdefault(e.name, set()).add(e.field_dict()["parent"])
+        assert parents["ctl.observe"] == {None}
+        assert parents["ctl.fit"] == {"ctl.observe"}
+        assert parents["replan"] == {"ctl.observe"}
+        assert parents["ctl.actuate"] == {"ctl.observe"}
+        assert parents["surface.dispatch"] == {"replan"}
+        assert parents["surface.fetch"] == {"surface.summarize"}
+        assert parents["surface.summarize"] == {"replan"}
 
 
 # ==========================================================================
