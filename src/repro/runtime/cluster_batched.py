@@ -86,14 +86,26 @@ def _kth_sort(nat, k):
     return jnp.sort(nat)[k - 1]
 
 
-def make_plain_step(k, cancel_overhead, preempt: bool, kth=_kth_sort):
+def _first_ties_cumsum(eq, take_eq):
+    """The first ``take_eq`` ties in worker-index order, by a prefix
+    count over all n workers — the historical rank, cheap at the
+    monolithic engine's widths (n ~ 10^2)."""
+    return eq & (jnp.cumsum(eq) * eq <= take_eq)
+
+
+def make_plain_step(k, cancel_overhead, preempt: bool, kth=_kth_sort,
+                    first_ties=_first_ties_cumsum):
     """The per-job step of the fault-free ungrouped lane, as a factory.
 
     Extracted so the monolithic scan (here) and the chunked fleet engine
-    (``runtime.fleet``) run the IDENTICAL recurrence; ``kth`` is the
-    order-statistic selection (sort here; the fleet engine swaps in an
-    exact bit-bisection at n ~ 10^4 where XLA's CPU sort is ~10x
-    slower — same value either way, so parity is unaffected).
+    (``runtime.fleet``) run the IDENTICAL recurrence.  ``kth`` is the
+    order-statistic selection and ``first_ties`` the rank among workers
+    tied at D (the first ``take_eq`` of the mask ``eq`` in index order).
+    Both default to the full-width forms (sort, prefix sum); the fleet
+    engine swaps in exact bisections — a bit-bisection for D and an
+    index bisection for the tie rank — at n ~ 10^4, where a sort or a
+    prefix sum over every worker dominates the step.  Same values either
+    way, so parity is unaffected.
     """
     def step(carry, inp):
         F, busy, wasted = carry
@@ -107,8 +119,7 @@ def make_plain_step(k, cancel_overhead, preempt: bool, kth=_kth_sort):
         # (k - #earlier) of the ties in index order
         lt = nat < D
         eq = nat == D
-        take_eq = k - lt.sum()
-        completed = lt | (eq & (jnp.cumsum(eq) * eq <= take_eq))
+        completed = lt | first_ties(eq, k - lt.sum())
         inservice = (~completed) & (start < D)
         if preempt:
             cut = D - start + cancel_overhead

@@ -44,7 +44,9 @@ memory-bounded streaming form:
     step bottleneck; the fault-free lane swaps in an exact radix
     bisection over the float32 bit patterns (``_kth_bisect``; ~9x
     faster at n=10^4, measured), bit-equal to ``sort(nat)[k-1]`` for
-    the non-negative finish times the recurrence produces.
+    the non-negative finish times the recurrence produces, and ranks
+    the workers tied at D by an index bisection
+    (``_first_ties_bisect``) instead of a prefix sum over all n.
 
 Entry points: ``fleet_sweep`` mirrors ``cluster_batched.sweep`` and
 returns the same ``ClusterSweep``; ``cluster_batched.sweep(...,
@@ -82,8 +84,9 @@ __all__ = ["FleetLanes", "FleetRaw", "build_fleet_lanes", "co_fleet_lanes",
 
 _FLEET_TRACES = 0
 
-#: below this width the plain sort selection wins; above it the radix
-#: bisection does (measured on CPU: ~9x at n = 10^4)
+#: below this width the plain sort selection and prefix-sum tie rank
+#: win; above it the bisections do (measured on CPU: ~9x at n = 10^4
+#: for the selection)
 _BISECT_MIN_N = 1024
 
 _DEFAULT_CHUNK = 512
@@ -130,6 +133,32 @@ def _kth_bisect(nat, k):
 
     out = jax.lax.fori_loop(0, 31, body, jnp.int32(0))
     return jax.lax.bitcast_convert_type(out, jnp.float32)
+
+
+def _first_ties_bisect(eq, take_eq):
+    """The first ``take_eq`` True entries of ``eq`` in index order, by
+    bisection on the index.
+
+    The answer is ``eq & (iota <= I)`` with I the index of the
+    ``take_eq``-th tie, i.e. the largest index with fewer than
+    ``take_eq`` ties strictly below it; building I bit by bit from the
+    MSB takes ceil(log2 n) counting passes (14 at n = 10^4) and no
+    prefix sum.  Bit-equal to ``cluster_batched._first_ties_cumsum``
+    for every 1 <= take_eq <= eq.sum(), which the step guarantees (D is
+    one of the values, and fewer than k lie strictly below it).  The
+    passes are unrolled: on a TPU v5e that takes 2.5% off the n = 10^4
+    sweep against the rolled loop.
+    """
+    n = eq.shape[-1]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    bits = max(1, (n - 1).bit_length())
+
+    def body(i, pre):
+        cand = pre | (jnp.int32(1) << (bits - 1 - i))
+        return jnp.where((eq & (iota < cand)).sum() < take_eq, cand, pre)
+
+    last = jax.lax.fori_loop(0, bits, body, jnp.int32(0), unroll=True)
+    return eq & (iota <= last)
 
 
 # --------------------------------------------------------------------------
@@ -261,7 +290,8 @@ def _fleet_core(key, rates, speeds, cancel_overhead, dist, arrivals, delta,
     else:
         crash = jnp.zeros((n, 0), jnp.float32)
         recover = crash
-    kth = _kth_bisect if n >= _BISECT_MIN_N else None
+    bisect_kw = (dict(kth=_kth_bisect, first_ties=_first_ties_bisect)
+                 if n >= _BISECT_MIN_N else {})
     num_chunks = -(-num_jobs // chunk)
 
     def run_lanes(lane_pack, shared):
@@ -326,9 +356,8 @@ def _fleet_core(key, rates, speeds, cancel_overhead, dist, arrivals, delta,
                     base_step = make_grouped_step(cancel_overhead, preempt,
                                                   rr, groups)
                 else:
-                    base_step = make_plain_step(
-                        kq, cancel_overhead, preempt,
-                        **({} if kth is None else {"kth": kth}))
+                    base_step = make_plain_step(kq, cancel_overhead,
+                                                preempt, **bisect_kw)
 
                 def step(carry, inp):
                     F1, busy, wasted, last = carry
@@ -496,6 +525,7 @@ def run_fleet(scenario: Scenario, loads: Sequence[float], lanes: FleetLanes,
     s_max = int(ls.max())
     have_fail = retry is not None
     delta = None if scenario.delta is None else jnp.float32(scenario.delta)
+    select = "bisect" if n >= _BISECT_MIN_N else "sort"
 
     acc = {k: [] for k in ("busy", "wasted", "horizon", "a_last", "lat",
                            "ok", "cnt", "mean", "m2", "res", "nok")}
@@ -563,7 +593,7 @@ def run_fleet(scenario: Scenario, loads: Sequence[float], lanes: FleetLanes,
                       rep=rep, reps=int(reps), n=n, lanes=B,
                       num_chunks=-(-int(num_jobs) // int(chunk)),
                       chunk=int(chunk), jobs=int(num_jobs),
-                      stream=bool(stream),
+                      stream=bool(stream), select=select,
                       compiled=_FLEET_TRACES > traces0)
 
     def stk(name):

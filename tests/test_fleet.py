@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.api import LoadAwareLatency
 from repro.assign import AllWorkers, RandomGroups, ReplicationGroups
@@ -21,11 +22,13 @@ from repro.core import BiModal, FailureModel, RetryPolicy, Scaling, ShiftedExp
 from repro.core.scenario import (DeterministicArrivals, MMPPArrivals,
                                  PoissonArrivals, Scenario, job_row_keys,
                                  sample_task_matrix)
-from repro.runtime.cluster_batched import (resolve_failure_args, sweep,
-                                           validate_sweep_args)
-from repro.runtime.fleet import (build_fleet_lanes, co_fleet_lanes,
-                                 default_chunk, fleet_compile_count,
-                                 fleet_sweep, run_fleet, summarize_fleet)
+from repro.runtime.cluster_batched import (_first_ties_cumsum, _kth_sort,
+                                           _scan_lane, resolve_failure_args,
+                                           sweep, validate_sweep_args)
+from repro.runtime.fleet import (_first_ties_bisect, build_fleet_lanes,
+                                 co_fleet_lanes, default_chunk,
+                                 fleet_compile_count, fleet_sweep,
+                                 run_fleet, summarize_fleet)
 
 SERVER = Scaling.SERVER_DEPENDENT
 METRICS = ("mean", "p50", "p95", "p99", "utilization", "wasted_frac",
@@ -182,6 +185,61 @@ class TestChunkParity:
         assert chnk.mean[0, 0] == pytest.approx(mono.mean[0, 0], rel=0.05)
         assert chnk.utilization[0, 0] == pytest.approx(
             mono.utilization[0, 0], rel=0.05)
+
+
+# ==========================================================================
+# the n >= _BISECT_MIN_N selections: bit-equal to sort + prefix sum
+# ==========================================================================
+
+class TestBisectionPath:
+    @pytest.mark.parametrize("n", [1024, 1025, 10_000])
+    def test_first_ties_bisect_equals_cumsum(self, n):
+        """The index-bisection tie rank picks exactly the ties the
+        prefix-sum rule picks, on integer rows where ties are heavy."""
+        lanes = 4
+        rng = np.random.default_rng(n)
+        nat = rng.integers(0, 4, (lanes, n)).astype(np.float32)
+        ks = np.stack([np.ones(lanes, np.int64), np.full(lanes, n),
+                       rng.integers(1, n + 1, lanes),
+                       rng.integers(1, n + 1, lanes)], axis=1)
+
+        def masks(row, k):
+            D = _kth_sort(row, k)
+            lt, eq = row < D, row == D
+            take = k - lt.sum()
+            return (lt | _first_ties_bisect(eq, take),
+                    lt | _first_ties_cumsum(eq, take))
+
+        run = jax.jit(jax.vmap(jax.vmap(masks, in_axes=(None, 0)),
+                               in_axes=(0, 0)))
+        got, want = run(jnp.asarray(nat), jnp.asarray(ks, jnp.int32))
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got.sum(-1), ks)
+
+    def test_fleet_matches_monolithic_at_n1024(self):
+        """n = 1024 is the first width on the bisection path: the fleet
+        engine's lanes equal the monolithic lane (sort + prefix sum) run
+        on the same draws, bit for bit, under two chunkings.  Dyadic
+        service (BiModal atoms {1, 4} times s) and arrivals (gap 4) keep
+        every sum exact, so any difference is a different mask."""
+        n, ks, jobs, load = 1024, [1, 256, 1024], 40, 0.25
+        sc = Scenario(BiModal(4.0, 0.25), SERVER, n,
+                      arrivals=DeterministicArrivals(rate=1.0))
+        raws = [_raw(sc, [load], ks, jobs, c) for c in (jobs, 16)]
+        rk = jax.random.split(jax.random.PRNGKey(3), 1)[0]
+        k_svc, _ = jax.random.split(rk)
+        z = jax.vmap(lambda kk: sc.dist.sample_noise(kk, (n,)))(
+            job_row_keys(k_svc, 0, jobs))
+        A = jnp.arange(1, jobs + 1, dtype=jnp.float32) / load
+        for i, k in enumerate(ks):
+            S = sc.dist.shift + (n // k) * z
+            lat, busy, wasted = _scan_lane(A, S, jnp.int32(k), 0.0, True)
+            for raw in raws:
+                np.testing.assert_array_equal(raw.lat[0, 0, i],
+                                              np.asarray(lat), err_msg=k)
+                assert raw.busy[0, 0, i] == float(busy), k
+                assert raw.wasted[0, 0, i] == float(wasted), k
 
 
 # ==========================================================================

@@ -556,6 +556,7 @@ class TestEngineSweepEvents:
         assert [e.name for e in evs] == ["fleet", "fleet"]
         f = evs[0].field_dict()
         assert f["rep"] == 0 and f["num_chunks"] == 3
+        assert f["select"] == "sort"            # n=6 < _BISECT_MIN_N
 
 
 # ==========================================================================
