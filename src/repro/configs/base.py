@@ -41,6 +41,25 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    # The dropless expert-share layer (models/moe.expert_share_ffn), used
+    # when ``experts_held`` > 0: a sigmoid router scores all
+    # ``num_experts``, and this chip computes experts [expert_first,
+    # expert_first + experts_held) at width ``expert_d_ff``, plus
+    # ``num_shared_experts`` shared ones.
+    experts_held: int = 0
+    expert_first: int = 0
+    expert_d_ff: int = 0
+    num_shared_experts: int = 0
+    route_scale: float = 1.0         # routing weights: scale * s / sum(s)
+    # the first ``num_dense_layers`` layers have a SwiGLU of width d_ff
+    num_dense_layers: int = 0
+    # per-layer attention kind, "sliding" (window attn_window, RoPE) or
+    # "global" (full, no position encoding); empty = all alike
+    layer_types: Tuple[str, ...] = ()
+    # "pre_norm", or "afmoe": the attention output gated as
+    # o <- o * sigmoid(h W_g) before W_o, an RMSNorm after attention and
+    # after the MLP too, and the embeddings scaled by sqrt(d_model)
+    block: str = "pre_norm"
     # SSM (mamba2 / hybrid)
     ssm_state: int = 0
     ssm_head_dim: int = 64

@@ -1,7 +1,7 @@
 """Family dispatch: one uniform model API over every assigned architecture.
 
     init_params / param_shapes / partition_specs
-    forward / loss_fn
+    forward / forward_aux / loss_fn
     init_cache / cache_shapes / cache_specs / decode_step
 
 ``transformer`` serves dense, MoE, encoder-only and embedding-input (vlm /
@@ -33,6 +33,16 @@ def partition_specs(cfg, fsdp: str = "data", tp: str = "model"):
 
 def forward(cfg, params, tokens, positions=None):
     return model_module(cfg).forward(cfg, params, tokens, positions)
+
+
+def forward_aux(cfg, params, tokens, positions=None):
+    """(logits, aux): aux holds the model's counters (the expert-share
+    layer's rows per held expert and its selections), empty where it
+    has none."""
+    mod = model_module(cfg)
+    if hasattr(mod, "forward_aux"):
+        return mod.forward_aux(cfg, params, tokens, positions)
+    return mod.forward(cfg, params, tokens, positions), {}
 
 
 def loss_fn(cfg, params, tokens, labels):

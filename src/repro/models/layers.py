@@ -195,10 +195,14 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
 
 
 def _block_mask(q_pos, kpos, causal, window, sq, blk):
+    """``window``: a static int (0 = full), or a traced int32 scalar (a
+    per-layer window scanned with the layers; <= 0 = full)."""
     mask = jnp.ones((sq, blk), dtype=bool)
     if causal:
         mask &= q_pos[:, None] >= kpos[None, :]
-        if window > 0:
+        if not isinstance(window, int):
+            mask &= (window <= 0) | (q_pos[:, None] - kpos[None, :] < window)
+        elif window > 0:
             mask &= q_pos[:, None] - kpos[None, :] < window
     return mask
 
@@ -336,6 +340,25 @@ def _flash_train_bwd(causal, window, block_kv, res, dout):
 _flash_train.defvjp(_flash_train_fwd, _flash_train_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_train_window(q, k, v, window, causal: bool, block_kv: int):
+    """``_flash_train`` with the window a traced int32 scalar."""
+    return _flash_train_fwd(q, k, v, causal, window, block_kv)[0]
+
+
+def _flash_window_fwd(q, k, v, window, causal, block_kv):
+    out, res = _flash_train_fwd(q, k, v, causal, window, block_kv)
+    return out, (res, window)
+
+
+def _flash_window_bwd(causal, block_kv, res, dout):
+    res, window = res
+    return _flash_train_bwd(causal, window, block_kv, res, dout) + (None,)
+
+
+_flash_train_window.defvjp(_flash_window_fwd, _flash_window_bwd)
+
+
 def attention(
     q: jax.Array,                    # (B, Sq, H, hd)
     k: jax.Array,                    # (B, Sk, KV, hd)
@@ -344,7 +367,8 @@ def attention(
     q_offset: Optional[jax.Array] = None,   # position of q[0] among keys
     block_kv: int = 1024,
     kv_len: Optional[jax.Array] = None,     # valid key prefix length (decode)
-    window: int = 0,                 # sliding window size (0 = full)
+    window=0,                        # sliding window size (0 = full);
+                                     # a traced scalar on the train path
 ) -> jax.Array:
     """Blockwise online-softmax attention; never materializes (Sq, Sk).
 
@@ -387,6 +411,8 @@ def attention(
     if kv_len is None:
         # Train / prefill full-sequence path: flash recurrence with a
         # recompute (flash) backward so AD never stores per-block softmax.
+        if not isinstance(window, int):
+            return _flash_train_window(q, k, v, window, causal, block_kv)
         return _flash_train(q, k, v, causal, window, block_kv)
 
     block = min(block_kv, sk)

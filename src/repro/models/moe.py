@@ -1,6 +1,7 @@
-"""Capacity-based top-k Mixture-of-Experts FFN (Mesh-TF / GSPMD style).
+"""Mixture-of-Experts FFNs: the capacity-based top-k layer (Mesh-TF /
+GSPMD style) and the dropless expert-share layer (below).
 
-Dense dispatch: tokens are grouped, routed top-k, and placed into per-expert
+The capacity layer uses dense dispatch: tokens are grouped, routed top-k, and placed into per-expert
 capacity slots via one-hot dispatch/combine einsums.  This is the
 GSPMD-friendly formulation (no ragged ops): the expert axis is sharded over
 the ``model`` mesh axis (expert parallelism) and the group axis over
@@ -14,10 +15,13 @@ convention).
 """
 from __future__ import annotations
 
+from typing import Optional, Tuple
+
 import jax
 import jax.numpy as jnp
 
-from .layers import constrain
+from ..kernels.grouped_matmul import grouped_matmul
+from .layers import constrain, swiglu
 
 
 def expert_capacity(group_size: int, num_experts: int, top_k: int,
@@ -96,3 +100,67 @@ def moe_ffn(
         xout = constrain(xout, "batch", "model", None, None)
         y = jnp.einsum("ngec,necd->ngd", combine, xout)
     return y.reshape(b, s, d)
+
+
+# --------------------------------------------------------------------------
+# Dropless expert-share layer (expert parallelism, one chip's share)
+# --------------------------------------------------------------------------
+
+def route(u: jax.Array, router: jax.Array, expert_bias: jax.Array,
+          top_k: int, route_scale: float = 1.0):
+    """Sigmoid router over ALL experts, in float32: (T, d) -> (idx (T, k)
+    int32, weights (T, k) float32).  s = sigmoid(u W_r); the experts are
+    the top-k of s + expert_bias (the bias moves selection only),
+    weighted route_scale * s_e / sum over the selected s."""
+    s = jax.nn.sigmoid(jnp.einsum("td,de->te", u.astype(jnp.float32),
+                                  router.astype(jnp.float32)))
+    _, idx = jax.lax.top_k(s + expert_bias.astype(jnp.float32), top_k)
+    sel = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, route_scale * sel / sel.sum(-1, keepdims=True)
+
+
+def expert_share_ffn(
+    x: jax.Array,            # (B, S, d)
+    router: jax.Array,       # (d, E) over all E experts
+    expert_bias: jax.Array,  # (E,) selection-only bias
+    w_gate: jax.Array,       # (H, d, f) the H experts held here
+    w_up: jax.Array,         # (H, d, f)
+    w_down: jax.Array,       # (H, f, d)
+    shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
+    top_k: int,
+    first: int = 0,
+    route_scale: float = 1.0,
+):
+    """This chip's part of a dropless MoE layer: (y (B, S, d), rows (H,),
+    choices (B, S, k)).
+
+    Every token is routed over all E experts; each (token, slot) pair
+    whose expert lies in [first, first + H) is computed, none dropped,
+    and weighted into y, beside the shared expert(s), which every chip
+    computes alike.  The T * k assignments are sorted by held expert,
+    the assignments to absent experts last, so the shapes are static and
+    the grouped matmul visits only the held groups (and returns zeros
+    past them).  ``rows`` counts the assignments each held expert
+    received; ``choices`` are the experts each token selected, held or
+    not.
+    """
+    b, s, d = x.shape
+    held = w_gate.shape[0]
+    u = x.reshape(b * s, d)
+    idx, w = route(u, router, expert_bias, top_k, route_scale)
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)
+    rows = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    xs = u[order // top_k]                                  # (T*k, d)
+    h = jax.nn.silu(grouped_matmul(xs, w_gate, rows)) \
+        * grouped_matmul(xs, w_up, rows)
+    out = grouped_matmul(h, w_down, rows)                   # 0 past held
+    back = jnp.argsort(order)                               # unsort
+    out = out[back].reshape(b * s, top_k, d)
+    wk = jnp.where(key < held, w.reshape(-1), 0.0).reshape(b * s, top_k)
+    y = jnp.einsum("tkd,tk->td", out, wk.astype(x.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+    if shared is not None:
+        y = y + swiglu(u, *shared)
+    return y.reshape(b, s, d), rows, idx.reshape(b, s, top_k)

@@ -59,6 +59,7 @@ EVENT_KINDS = frozenset({
     "slo_alarm",        # the SLO monitor's multi-window burn crossed
     "infeasible",       # a commit aborted: no finite cell on the surface
     "sweep",            # one cluster-engine surface call (batched/fleet rep)
+    "expert_load",      # a train step's rows per held expert, by layer
     "span",             # a closed span (name, start ts, duration)
     "mark",             # free-form annotation (regime boundaries, footers)
 })

@@ -79,13 +79,19 @@ def weighted_loss_fn(cfg: ModelConfig) -> Callable:
     mean over coded rows equals the plain mean over unique rows when the
     weights come from ``decode_example_weights``.
     """
+    loss = weighted_loss_aux_fn(cfg)
+    return lambda *args: loss(*args)[0]
+
+
+def weighted_loss_aux_fn(cfg: ModelConfig) -> Callable:
+    """``weighted_loss_fn`` returning (loss, the model's counters)."""
     def loss(params, tokens, labels, weights):
-        logits = api.forward(cfg, params, tokens)
+        logits, aux = api.forward_aux(cfg, params, tokens)
         logits = logits.astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         nll = (logz - gold).mean(axis=-1)          # (B,) per-example
-        return (nll * weights).mean()
+        return (nll * weights).mean(), aux
     return loss
 
 
@@ -93,14 +99,19 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig) -> Callable:
     """(params, opt_state, tokens, labels, weights) -> (params, opt, metrics).
 
     The returned function is pjit-able; decode weights ride in as data.
+    The metrics carry the model's counters beside the loss
+    (``expert_rows``: the assignments each held expert received, and
+    ``expert_choices``: the experts each token selected, by expert layer).
     """
-    loss = weighted_loss_fn(cfg)
+    loss = weighted_loss_aux_fn(cfg)
 
     def step(params, opt_state, tokens, labels, weights):
-        lval, grads = jax.value_and_grad(loss)(params, tokens, labels, weights)
+        (lval, aux), grads = jax.value_and_grad(loss, has_aux=True)(
+            params, tokens, labels, weights)
         params, opt_state, metrics = adamw.apply_updates(
             opt_cfg, params, grads, opt_state)
         metrics["loss"] = lval
+        metrics.update(aux)
         return params, opt_state, metrics
 
     return step
@@ -115,17 +126,21 @@ def make_coded_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig,
     repeat-to-examples and mean-normalization scale are constants folded
     into the compiled program (``expand_worker_weights``), eliminating the
     per-step host loop and the (coded_rows,) transfer of the seed path.
+    The metrics carry the model's counters beside the loss, as
+    ``make_train_step``'s do.
     """
-    loss = weighted_loss_fn(cfg)
+    loss = weighted_loss_aux_fn(cfg)
     per_worker_rows = step_cfg.per_worker_rows
     scale = step_cfg.coded_batch_rows / step_cfg.unique_batch
 
     def step(params, opt_state, tokens, labels, worker_weights):
         weights = expand_worker_weights(worker_weights, per_worker_rows, scale)
-        lval, grads = jax.value_and_grad(loss)(params, tokens, labels, weights)
+        (lval, aux), grads = jax.value_and_grad(loss, has_aux=True)(
+            params, tokens, labels, weights)
         params, opt_state, metrics = adamw.apply_updates(
             opt_cfg, params, grads, opt_state)
         metrics["loss"] = lval
+        metrics.update(aux)
         return params, opt_state, metrics
 
     return step
@@ -258,5 +273,10 @@ class CodedTrainer:
             alive = self.gather_alive(step)
             a = self.decode_coefficients(alive)
         with _trace.span("train.dispatch"):
-            return self.step_fn(params, opt_state, jnp.asarray(toks),
-                                jnp.asarray(labs), jnp.asarray(a))
+            out = self.step_fn(params, opt_state, jnp.asarray(toks),
+                               jnp.asarray(labs), jnp.asarray(a))
+        if _trace.active() is not None and "expert_rows" in out[2]:
+            # the counters reach the host only while a recorder is on
+            _trace.event("expert_load", step=int(step),
+                         rows=np.asarray(out[2]["expert_rows"]).tolist())
+        return out
