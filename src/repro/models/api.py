@@ -45,6 +45,12 @@ def forward_aux(cfg, params, tokens, positions=None):
     return mod.forward(cfg, params, tokens, positions), {}
 
 
+def attn_tiles(cfg, seq_len):
+    """(visited, total) attention score tiles of one full-sequence
+    forward, summed over the model's attention layers."""
+    return model_module(cfg).attn_tiles(cfg, seq_len)
+
+
 def loss_fn(cfg, params, tokens, labels):
     return model_module(cfg).loss_fn(cfg, params, tokens, labels)
 

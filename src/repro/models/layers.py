@@ -15,6 +15,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 
@@ -207,44 +208,116 @@ def _block_mask(q_pos, kpos, causal, window, sq, blk):
     return mask
 
 
+def _tile_bounds(nq, nk, block, off, causal, window):
+    """Key blocks [lo, hi) that each of ``nq`` query blocks visits: those
+    holding a key its mask admits.  Query block i holds positions
+    ``off + i * block ...``; causal attention stops at the block of its
+    last query, and a window > 0 starts at the block of ``q_lo - window +
+    1``.  Every key outside [lo, hi) would be masked.  Numpy arrays where
+    the window is a static int, traced ones where it is traced."""
+    xp = np if isinstance(window, int) else jnp
+    i = xp.arange(nq, dtype=xp.int32)
+    if not causal:
+        return xp.zeros_like(i), xp.full_like(i, nk)
+    hi = xp.minimum((off + (i + 1) * block - 1) // block + 1, nk)
+    lo = xp.where(window > 0,
+                  xp.maximum((off + i * block - window + 1) // block, 0), 0)
+    return lo, hi
+
+
+def flash_tiles(seq_len: int, block: int, window: int = 0,
+                causal: bool = True) -> Tuple[int, int]:
+    """(visited, total) (query block x key block) score tiles of the train
+    path's flash attention over ``seq_len`` tokens in blocks of ``block``
+    (at most ``seq_len``), from the bounds the schedule itself uses."""
+    block = min(block, seq_len)
+    n = -(-seq_len // block)
+    lo, hi = _tile_bounds(n, n, block, 0, causal, window)
+    return int((hi - lo).sum()), n * n
+
+
+def _fwd_tile(carry, q32, kblk, vblk, q_pos, blk_idx, causal, window,
+              block, sk, pad, neg):
+    """One key block into the online-softmax state (m, l, acc) of the
+    queries ``q32`` (B, sq, H, hd) at positions ``q_pos``; masked scores
+    read ``neg``."""
+    m, l, acc = carry
+    kpos = blk_idx * block + jnp.arange(block, dtype=jnp.int32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q32, kblk.astype(jnp.float32))
+    mask = _block_mask(q_pos, kpos, causal, window, q_pos.shape[0], block)
+    if pad:
+        mask &= (kpos[None, :] < sk)
+    s = jnp.where(mask[None, None], s, neg)
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    corr = jnp.exp(m - m_new)
+    if opt_enabled("attn_probs16"):
+        # ONE score-sized tensor in the compute dtype; its row sum
+        # accumulates in fp32 inside the reduce
+        p = jnp.exp(s - m_new[..., None]).astype(vblk.dtype)
+        l_new = l * corr + jnp.sum(p, axis=-1, dtype=jnp.float32)
+    else:
+        p = jnp.exp(s - m_new[..., None])
+        l_new = l * corr + p.sum(axis=-1)
+    acc_new = acc * corr[..., None] + jnp.einsum(
+        "bhqk,bkhd->bhqd", p, vblk.astype(p.dtype),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+def _softmax_init(b, h, sq, hd, neg):
+    return (jnp.full((b, h, sq), neg, dtype=jnp.float32),
+            jnp.zeros((b, h, sq), dtype=jnp.float32),
+            jnp.zeros((b, h, sq, hd), dtype=jnp.float32))
+
+
+def _softmax_out(m, l, acc):
+    l = jnp.maximum(l, 1e-30)
+    return acc / l[..., None], m + jnp.log(l)
+
+
 def _flash_fwd_scan(q32, kb, vb, q_pos, causal, window, sq, block, sk, pad):
-    """Online-softmax forward.  q32 (B,Sq,H,hd) pre-scaled fp32;
-    kb/vb (nblocks, B, block, H, hd).  Returns (out fp32 (B,H,Sq,hd), lse)."""
+    """Online-softmax forward of all queries over every key block.  q32
+    (B,Sq,H,hd) pre-scaled fp32; kb/vb (nblocks, B, block, H, hd).
+    Returns (out fp32 (B,H,Sq,hd), lse)."""
     b, _, h, hd = q32.shape
     neg = jnp.float32(-1e30)
 
     def body(carry, inputs):
-        m, l, acc = carry
         kblk, vblk, blk_idx = inputs
-        kpos = blk_idx * block + jnp.arange(block, dtype=jnp.int32)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q32, kblk.astype(jnp.float32))
-        mask = _block_mask(q_pos, kpos, causal, window, sq, block)
-        if pad:
-            mask &= (kpos[None, :] < sk)
-        s = jnp.where(mask[None, None], s, neg)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        corr = jnp.exp(m - m_new)
-        if opt_enabled("attn_probs16"):
-            # ONE score-sized tensor in the compute dtype; its row sum
-            # accumulates in fp32 inside the reduce
-            p = jnp.exp(s - m_new[..., None]).astype(vblk.dtype)
-            l_new = l * corr + jnp.sum(p, axis=-1, dtype=jnp.float32)
-        else:
-            p = jnp.exp(s - m_new[..., None])
-            l_new = l * corr + p.sum(axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "bhqk,bkhd->bhqd", p, vblk.astype(p.dtype),
-            preferred_element_type=jnp.float32)
-        return (m_new, l_new, acc_new), None
+        return _fwd_tile(carry, q32, kblk, vblk, q_pos, blk_idx, causal,
+                         window, block, sk, pad, neg), None
 
-    m0 = jnp.full((b, h, sq), neg, dtype=jnp.float32)
-    l0 = jnp.zeros((b, h, sq), dtype=jnp.float32)
-    a0 = jnp.zeros((b, h, sq, hd), dtype=jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0), (kb, vb, jnp.arange(kb.shape[0], dtype=jnp.int32)))
-    l = jnp.maximum(l, 1e-30)
-    out = acc / l[..., None]
-    lse = m + jnp.log(l)
+        body, _softmax_init(b, h, sq, hd, neg),
+        (kb, vb, jnp.arange(kb.shape[0], dtype=jnp.int32)))
+    return _softmax_out(m, l, acc)
+
+
+def _flash_fwd_tiles(q32, kb, vb, off, causal, window, block, sk, pad):
+    """The forward over query blocks of ``block``, each visiting only the
+    key blocks ``_tile_bounds`` gives it (a run-time range where the
+    window is traced).  Returns (out fp32 (nq,B,H,block,hd), lse
+    (nq,B,H,block)); padded query rows are the caller's to drop."""
+    b, _, h, hd = q32.shape
+    qb, _ = _blocks(q32, block)
+    nq, nk = qb.shape[0], kb.shape[0]
+    lo, hi = _tile_bounds(nq, nk, block, off, causal, window)
+    neg = jnp.float32(-1e30)
+
+    def q_step(_, inputs):
+        qt, i, lo_i, hi_i = inputs
+        q_pos = off + i * block + jnp.arange(block, dtype=jnp.int32)
+
+        def k_step(j, carry):
+            return _fwd_tile(carry, qt, kb[j], vb[j], q_pos, j, causal,
+                             window, block, sk, pad, neg)
+
+        m, l, acc = jax.lax.fori_loop(lo_i, hi_i, k_step,
+                                      _softmax_init(b, h, block, hd, neg))
+        return None, _softmax_out(m, l, acc)
+
+    _, (out, lse) = jax.lax.scan(
+        q_step, None, (qb, jnp.arange(nq, dtype=jnp.int32), lo, hi))
     return out, lse
 
 
@@ -255,6 +328,8 @@ def _flash_train(q, k, v, causal: bool, window: int, block_kv: int):
     q (B,Sq,H,hd); k/v (B,Sk,H,hd) (GQA repeat done by the caller).
     The custom VJP recomputes per-block scores in the backward pass, so AD
     never stores the (Sq x Sk) softmax -- O(S) residuals (q,k,v,out,lse).
+    With more than one key block the queries are blocked too, and each
+    (query block, key block) tile the mask closes wholly is skipped.
     """
     out, _ = _flash_train_fwd(q, k, v, causal, window, block_kv)
     return out
@@ -269,6 +344,12 @@ def _blocks(x, block):
     return x.reshape(b, nb, block, h, hd).transpose(1, 0, 2, 3, 4), pad
 
 
+def _unblocks(xb, s):
+    """(nb, B, block, H, hd) -> (B, s, H, hd), the padding dropped."""
+    nb, b, block, h, hd = xb.shape
+    return xb.transpose(1, 0, 2, 3, 4).reshape(b, nb * block, h, hd)[:, :s]
+
+
 def _flash_train_fwd(q, k, v, causal, window, block_kv):
     b, sq, h, hd = q.shape
     sk = k.shape[1]
@@ -277,6 +358,11 @@ def _flash_train_fwd(q, k, v, causal, window, block_kv):
     q32 = q.astype(jnp.float32) * scale
     kb, pad = _blocks(k, block)
     vb, _ = _blocks(v, block)
+    if sk > block:
+        out32, lse = _flash_fwd_tiles(q32, kb, vb, sk - sq, causal, window,
+                                      block, sk, pad)
+        out = _unblocks(out32.transpose(0, 1, 3, 2, 4), sq).astype(q.dtype)
+        return out, (q, k, v, out, lse)
     q_pos = (sk - sq) + jnp.arange(sq, dtype=jnp.int32)
     out32, lse = _flash_fwd_scan(q32, kb, vb, q_pos, causal, window,
                                  sq, block, sk, pad)
@@ -284,11 +370,47 @@ def _flash_train_fwd(q, k, v, causal, window, block_kv):
     return out, (q, k, v, out, lse)
 
 
+def _bwd_tile(dq_acc, q, q32, dout, do32, lse, delta, kblk, vblk, q_pos,
+              blk_idx, causal, window, block, sk, pad, scale):
+    """The recompute backward of one (queries, key block) tile: dq_acc
+    plus this block's share of dq, and the block's dk and dv."""
+    kpos = blk_idx * block + jnp.arange(block, dtype=jnp.int32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q32 * scale,
+                   kblk.astype(jnp.float32))
+    mask = _block_mask(q_pos, kpos, causal, window, q_pos.shape[0], block)
+    if pad:
+        mask &= (kpos[None, :] < sk)
+    s = jnp.where(mask[None, None], s, -1e30)
+    if opt_enabled("attn_probs16"):
+        p = jnp.exp(s - lse[..., None]).astype(vblk.dtype)
+        dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p, dout.astype(p.dtype),
+                            preferred_element_type=jnp.float32)
+        dp = jnp.einsum("bqhd,bkhd->bhqk", do32,
+                        vblk.astype(jnp.float32))
+        ds = p * ((dp - delta[..., None]) * scale).astype(p.dtype)
+        dq_acc = dq_acc + jnp.einsum("bhqk,bkhd->bqhd", ds, kblk,
+                                     preferred_element_type=jnp.float32)
+        dk_blk = jnp.einsum("bhqk,bqhd->bkhd", ds, q.astype(ds.dtype),
+                            preferred_element_type=jnp.float32)
+    else:
+        p32 = jnp.exp(s - lse[..., None])
+        dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p32, do32)
+        dp = jnp.einsum("bqhd,bkhd->bhqk", do32,
+                        vblk.astype(jnp.float32))
+        ds = p32 * (dp - delta[..., None]) * scale
+        dq_acc = dq_acc + jnp.einsum("bhqk,bkhd->bqhd", ds,
+                                     kblk.astype(jnp.float32))
+        dk_blk = jnp.einsum("bhqk,bqhd->bkhd", ds, q32)
+    return dq_acc, dk_blk, dv_blk
+
+
 def _flash_train_bwd(causal, window, block_kv, res, dout):
     q, k, v, out, lse = res
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     block = min(block_kv, sk)
+    if sk > block:
+        return _flash_bwd_tiles(causal, window, block, res, dout)
     scale = 1.0 / math.sqrt(hd)
     q32 = q.astype(jnp.float32)
     do32 = dout.astype(jnp.float32)
@@ -300,41 +422,65 @@ def _flash_train_bwd(causal, window, block_kv, res, dout):
 
     def body(dq_acc, inputs):
         kblk, vblk, blk_idx = inputs
-        kpos = blk_idx * block + jnp.arange(block, dtype=jnp.int32)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q32 * scale,
-                       kblk.astype(jnp.float32))
-        mask = _block_mask(q_pos, kpos, causal, window, sq, block)
-        if pad:
-            mask &= (kpos[None, :] < sk)
-        s = jnp.where(mask[None, None], s, -1e30)
-        if opt_enabled("attn_probs16"):
-            p = jnp.exp(s - lse[..., None]).astype(vblk.dtype)
-            dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p, dout.astype(p.dtype),
-                                preferred_element_type=jnp.float32)
-            dp = jnp.einsum("bqhd,bkhd->bhqk", do32,
-                            vblk.astype(jnp.float32))
-            ds = p * ((dp - delta[..., None]) * scale).astype(p.dtype)
-            dq_acc = dq_acc + jnp.einsum("bhqk,bkhd->bqhd", ds, kblk,
-                                         preferred_element_type=jnp.float32)
-            dk_blk = jnp.einsum("bhqk,bqhd->bkhd", ds, q.astype(ds.dtype),
-                                preferred_element_type=jnp.float32)
-        else:
-            p32 = jnp.exp(s - lse[..., None])
-            dv_blk = jnp.einsum("bhqk,bqhd->bkhd", p32, do32)
-            dp = jnp.einsum("bqhd,bkhd->bhqk", do32,
-                            vblk.astype(jnp.float32))
-            ds = p32 * (dp - delta[..., None]) * scale
-            dq_acc = dq_acc + jnp.einsum("bhqk,bkhd->bqhd", ds,
-                                         kblk.astype(jnp.float32))
-            dk_blk = jnp.einsum("bhqk,bqhd->bkhd", ds, q32)
+        dq_acc, dk_blk, dv_blk = _bwd_tile(
+            dq_acc, q, q32, dout, do32, lse, delta, kblk, vblk, q_pos,
+            blk_idx, causal, window, block, sk, pad, scale)
         return dq_acc, (dk_blk, dv_blk)
 
     dq0 = jnp.zeros((b, sq, h, hd), jnp.float32)
     dq, (dkb, dvb) = jax.lax.scan(
         body, dq0, (kb, vb, jnp.arange(kb.shape[0], dtype=jnp.int32)))
-    dk = dkb.transpose(1, 0, 2, 3, 4).reshape(b, -1, h, hd)[:, :sk]
-    dv = dvb.transpose(1, 0, 2, 3, 4).reshape(b, -1, h, hd)[:, :sk]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return (dq.astype(q.dtype), _unblocks(dkb, sk).astype(k.dtype),
+            _unblocks(dvb, sk).astype(v.dtype))
+
+
+def _flash_bwd_tiles(causal, window, block, res, dout):
+    """The backward over the forward's tiles in one pass: key blocks
+    outside, each visiting the query blocks that see it; dk and dv
+    accumulate over those, dq per query block in place."""
+    q, k, v, out, lse = res                  # lse (nq, B, H, block)
+    sq, sk = q.shape[1], k.shape[1]
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    qb, _ = _blocks(q, block)
+    q32b = qb.astype(jnp.float32)
+    dob, _ = _blocks(dout, block)
+    do32b = dob.astype(jnp.float32)
+    delta = jnp.einsum("nbqhd,nbqhd->nbhq", do32b,
+                       _blocks(out, block)[0].astype(jnp.float32))
+    kb, pad = _blocks(k, block)
+    vb, _ = _blocks(v, block)
+    nq, nk = qb.shape[0], kb.shape[0]
+    lo, hi = _tile_bounds(nq, nk, block, sk - sq, causal, window)
+    # key block j is seen by the query blocks [q_lo, q_hi): lo, hi rise
+    # with the query block
+    j = jnp.arange(nk, dtype=jnp.int32)
+    q_lo = jnp.sum(hi[None, :] <= j[:, None], axis=1, dtype=jnp.int32)
+    q_hi = jnp.sum(lo[None, :] <= j[:, None], axis=1, dtype=jnp.int32)
+
+    def k_step(dq, inputs):
+        kblk, vblk, blk_idx, q_lo_j, q_hi_j = inputs
+
+        def q_step(i, carry):
+            dq, dk, dv = carry
+            q_pos = (sk - sq) + i * block + jnp.arange(block,
+                                                       dtype=jnp.int32)
+            dq_i, dk_i, dv_i = _bwd_tile(
+                dq[i], qb[i], q32b[i], dob[i], do32b[i], lse[i], delta[i],
+                kblk, vblk, q_pos, blk_idx, causal, window, block, sk, pad,
+                scale)
+            return dq.at[i].set(dq_i), dk + dk_i, dv + dv_i
+
+        zero = jnp.zeros(kblk.shape, jnp.float32)
+        dq, dk, dv = jax.lax.fori_loop(q_lo_j, q_hi_j, q_step,
+                                       (dq, zero, zero))
+        return dq, (dk, dv)
+
+    dq, (dkb, dvb) = jax.lax.scan(
+        k_step, jnp.zeros(qb.shape, jnp.float32),
+        (kb, vb, j, q_lo, q_hi))
+    return (_unblocks(dq, sq).astype(q.dtype),
+            _unblocks(dkb, sk).astype(k.dtype),
+            _unblocks(dvb, sk).astype(v.dtype))
 
 
 _flash_train.defvjp(_flash_train_fwd, _flash_train_bwd)
@@ -377,6 +523,9 @@ def attention(
     -- via lax.scan, so peak memory is O(B*H*Sq*block) and XLA can overlap
     the chunk matmuls.  Handles GQA by repeating KV heads, causal masks via
     q_offset, decode via kv_len masking, and sliding-window attention.
+    The train path (no ``kv_len``, several key blocks) blocks the queries
+    too and visits only the tiles the causal and window masks leave open
+    (``flash_tiles``), O(B*H*block^2) scores at a time.
     """
     b, sq, h, hd = q.shape
     sk = k.shape[1]

@@ -36,6 +36,15 @@ def _n_attn_sites(cfg: ModelConfig) -> int:
     return cfg.num_layers // cfg.attn_every
 
 
+def attn_tiles(cfg: ModelConfig, seq_len: int):
+    """(visited, total) attention score tiles of one full-sequence
+    forward: the shared block's calls (``layers.flash_tiles``)."""
+    visited, total = L.flash_tiles(seq_len, cfg.flash_block_kv,
+                                   cfg.attn_window)
+    n = _n_attn_sites(cfg)
+    return n * visited, n * total
+
+
 # --------------------------------------------------------------------------
 # Parameters
 # --------------------------------------------------------------------------

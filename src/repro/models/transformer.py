@@ -292,14 +292,29 @@ def _block(cfg: ModelConfig, lp: Params, x: jax.Array, positions: jax.Array,
     return L.constrain(x + f, "batch", None, None), counters
 
 
+def _windows(cfg: ModelConfig, lo: int, hi: int):
+    return [cfg.attn_window if k == "sliding" else 0
+            for k in cfg.layer_types[lo:hi]]
+
+
 def layer_kinds(cfg: ModelConfig, lo: int, hi: int):
     """(window, rope) int32 (hi - lo,) of layers lo..hi-1: a "sliding"
     layer attends within ``attn_window`` with RoPE, a "global" one over
     every earlier position with no position encoding."""
     kinds = cfg.layer_types[lo:hi]
-    window = [cfg.attn_window if k == "sliding" else 0 for k in kinds]
-    return (jnp.asarray(window, jnp.int32),
+    return (jnp.asarray(_windows(cfg, lo, hi), jnp.int32),
             jnp.asarray([int(k == "sliding") for k in kinds], jnp.int32))
+
+
+def attn_tiles(cfg: ModelConfig, seq_len: int):
+    """(visited, total) attention score tiles of one full-sequence
+    forward over ``seq_len`` tokens, summed over the layers
+    (``layers.flash_tiles``)."""
+    windows = (_windows(cfg, 0, cfg.num_layers) if cfg.layer_types
+               else [0] * cfg.num_layers)
+    tiles = [L.flash_tiles(seq_len, cfg.flash_block_kv, w, cfg.causal)
+             for w in windows]
+    return sum(t[0] for t in tiles), sum(t[1] for t in tiles)
 
 
 def _embed(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:

@@ -60,6 +60,7 @@ EVENT_KINDS = frozenset({
     "infeasible",       # a commit aborted: no finite cell on the surface
     "sweep",            # one cluster-engine surface call (batched/fleet rep)
     "expert_load",      # a train step's rows per held expert, by layer
+    "attn_tiles",       # a built train step's attention tiles visited/total
     "span",             # a closed span (name, start ts, duration)
     "mark",             # free-form annotation (regime boundaries, footers)
 })

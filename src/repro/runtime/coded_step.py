@@ -210,6 +210,7 @@ class CodedTrainer:
         self.step_fn = jax.jit(
             step, donate_argnums=(0, 1) if self._donate else ()) \
             if self._jit else step
+        self._tiles_due = True            # one attn_tiles event per build
 
     def decode_coefficients(self, alive: np.ndarray) -> np.ndarray:
         """(n_workers,) decode coefficients a_i for this step's alive mask."""
@@ -275,8 +276,15 @@ class CodedTrainer:
         with _trace.span("train.dispatch"):
             out = self.step_fn(params, opt_state, jnp.asarray(toks),
                                jnp.asarray(labs), jnp.asarray(a))
-        if _trace.active() is not None and "expert_rows" in out[2]:
-            # the counters reach the host only while a recorder is on
-            _trace.event("expert_load", step=int(step),
-                         rows=np.asarray(out[2]["expert_rows"]).tolist())
+        if _trace.active() is not None:
+            if self._tiles_due:
+                visited, total = api.attn_tiles(self.model_cfg,
+                                                self.data_cfg.seq_len)
+                _trace.event("attn_tiles", step=int(step), visited=visited,
+                             total=total)
+                self._tiles_due = False
+            if "expert_rows" in out[2]:
+                # the counters reach the host only while a recorder is on
+                _trace.event("expert_load", step=int(step),
+                             rows=np.asarray(out[2]["expert_rows"]).tolist())
         return out

@@ -221,6 +221,35 @@ def test_trainer_records_expert_load_only_while_recording(setup):
     assert rec.events("expert_load") == []
 
 
+def test_trainer_records_attn_tiles_once_per_build_while_recording(setup):
+    """``CodedTrainer.run_step`` emits one ``attn_tiles`` event per built
+    step, at the first step run while a recorder is on: the score tiles
+    the flash scan visits of all it could, summed over the layers."""
+    from repro.data import DataConfig
+    from repro.obs import recording
+    from repro.runtime import CodedStepConfig, CodedTrainer
+    cfg, params, _, _ = setup
+    opt_cfg = adamw.AdamWConfig(lr=1e-3, warmup_steps=1, decay_steps=10)
+    step_cfg = CodedStepConfig(n_workers=4, c=2, unique_batch=2)
+    trainer = CodedTrainer(cfg, DataConfig(TINY["vocab_size"], S, 2, seed=4),
+                           step_cfg, opt_cfg, donate=False)
+    opt = adamw.init(opt_cfg, params)
+    trainer.run_step(params, opt, 0)              # no recorder: no event
+    with recording() as rec:
+        trainer.run_step(params, opt, 1)
+        trainer.run_step(params, opt, 2)
+    (ev,) = rec.events("attn_tiles")
+    # 24 tokens in blocks of 8: 5 sliding layers (window 8) visit 5 of 9
+    # tiles, the global one 6
+    assert ev.field_dict() == {"step": 1, "visited": 5 * 5 + 6,
+                               "total": 6 * 9}
+    assert api.attn_tiles(cfg, S) == (31, 54)
+    trainer.step_cfg = step_cfg                   # a new build
+    with recording() as rec:
+        trainer.run_step(params, opt, 3)
+    assert [e.field_dict()["step"] for e in rec.events("attn_tiles")] == [3]
+
+
 # --------------------------------------------------------------------------
 # the expert-share layer
 # --------------------------------------------------------------------------
